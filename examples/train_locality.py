@@ -106,9 +106,10 @@ def main() -> None:
 
 def _compression_demo() -> None:
     """int8 EF gradient reduction on a toy problem (single host demo)."""
+    from repro.launch.mesh import make_mesh
     from repro.train.compress import init_error_state, make_compressed_grad_fn
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     w = jnp.zeros((8,))
     xs = np.random.default_rng(0).normal(size=(64, 8)).astype(np.float32)
     ys = xs @ np.arange(8, dtype=np.float32)

@@ -34,6 +34,7 @@ __all__ = [
     "Interval",
     "KernelContract",
     "RangeClaim",
+    "VMEM_LIMIT_BYTES",
     "choice",
     "contract",
     "lattice",
@@ -43,6 +44,13 @@ __all__ = [
 
 INT32_MIN = -(1 << 31)
 INT32_MAX = (1 << 31) - 1
+
+# Scoped VMEM the single-block Pallas kernels (water level, RD strip) ask
+# the TPU compiler for, and so kernelcheck's default memory budget: its
+# proof is a claim about this compile.  The compiler's default, 16 MiB,
+# is less than both kernels need at their ceilings; a v5e core has
+# 128 MiB.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 _DTYPE_BOUNDS = {
     "int32": (INT32_MIN, INT32_MAX),

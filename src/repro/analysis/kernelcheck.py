@@ -6,8 +6,9 @@ modules that declare :func:`repro.analysis.contracts.contract` entries
 adapters), sweeps each contract's boundary-focused geometry lattice, and
 proves four properties per entry point **without executing on any device**:
 
-- **memory** — summed VMEM footprint of the declared Pallas blocks stays
-  within the budget (``--budget-mb``, default one TPU core's ~16 MiB);
+- **memory** — summed VMEM footprint of the declared Pallas blocks, each
+  padded to whole (8, 128) tiles, stays within the budget
+  (``--budget-mb``, default the kernels' scoped VMEM limit, 64 MiB);
 - **range** — interval claims over the declared input envelope fit their
   dtypes / bit-fields (packed server ids, prefix sums, eq. 2 carries);
 - **coverage** — every lattice point, including past-ceiling probes,
@@ -40,7 +41,7 @@ import os
 import sys
 from typing import Any
 
-from .contracts import CONTRACTS, KernelContract, lattice
+from .contracts import CONTRACTS, VMEM_LIMIT_BYTES, KernelContract, lattice
 
 __all__ = ["DEFAULT_BUDGET_BYTES", "DEFAULT_MODULES", "check_contract", "main"]
 
@@ -52,8 +53,13 @@ DEFAULT_MODULES = (
     "repro.core.rd_jax",
 )
 
-# One TPU core's VMEM (~16 MiB); per-invocation blocks must fit well inside.
-DEFAULT_BUDGET_BYTES = 16 * 1024 * 1024
+# The scoped VMEM the kernels request from the TPU compiler.
+DEFAULT_BUDGET_BYTES = VMEM_LIMIT_BYTES
+
+# A VMEM tile is (8 sublanes of 32 bits, 128 lanes): narrower dtypes
+# pack more rows into a sublane.
+_TILE_SUBLANE_BYTES = 8 * 4
+_TILE_LANES = 128
 
 DEFAULT_REPORT = os.path.join("results", "KERNELCHECK.json")
 
@@ -76,10 +82,21 @@ class CheckViolation:
         }
 
 
+def _tiled_bytes(shape: tuple[int, ...], itemsize: int) -> int:
+    """Bytes a block occupies in VMEM: its last two dims round up to
+    whole tiles (a ``(1, n)`` int32 row takes eight sublanes); leading
+    dims count separate tiled arrays.  A 1-D block is one row."""
+    *lead, rows, lanes = (1, 1, *shape)[-max(2, len(shape)):]
+    sublanes = _TILE_SUBLANE_BYTES // itemsize
+    rows = -(-rows // sublanes) * sublanes
+    lanes = -(-lanes // _TILE_LANES) * _TILE_LANES
+    return int(math.prod(lead)) * rows * lanes * itemsize
+
+
 def _block_bytes(blocks: Any) -> tuple[int, dict[str, int]]:
     per_block: dict[str, int] = {}
     for name, (shape, itemsize) in blocks.items():
-        per_block[name] = int(math.prod(shape)) * int(itemsize)
+        per_block[name] = _tiled_bytes(tuple(shape), int(itemsize))
     return sum(per_block.values()), per_block
 
 

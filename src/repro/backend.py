@@ -16,9 +16,9 @@ single resolution point:
 device, CPU → host/jnp) stays with the consumer
 (:func:`repro.kernels.waterlevel.resolve_use_pallas`,
 :func:`repro.core.rd.resolve_rd_backend`) because *this* module must
-never import jax: RD's host path resolves its backend inside the first
-arrival's timed scheduling step, and a multi-second jax import does not
-belong there.
+never import jax: a run scoped to ``set_backend(rd="host")`` resolves
+its backend inside the first arrival's timed scheduling step, and a
+multi-second jax import does not belong there.
 """
 
 from __future__ import annotations

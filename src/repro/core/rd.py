@@ -55,7 +55,6 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
-import sys
 
 import numpy as np
 
@@ -92,22 +91,20 @@ def resolve_rd_backend(explicit: str | None = None) -> str:
     faster of the three — the ``--rd-sweep`` benchmark tracks all
     backends).
 
-    Mirrors :func:`repro.kernels.waterlevel.resolve_use_pallas`, with one
-    twist: this function lives on the host side and never *imports* jax —
-    ``auto`` consults :func:`jax.default_backend` only when jax is
-    already loaded.  A TPU session imports jax long before scheduling,
-    while a pure-host run must not pay a multi-second jax import inside
-    the first arrival's timed scheduling path.
+    Mirrors :func:`repro.kernels.waterlevel.resolve_use_pallas`.  Only
+    ``auto`` imports jax (to ask for the platform), so an explicit
+    ``host`` scope stays jax-free.  :class:`repro.runtime.SchedulingEngine`
+    calls this once at construction for RD policies, so the import never
+    lands in an arrival's timed scheduling overhead.
     """
     from repro import backend as backend_config
 
     choice = backend_config.resolve("rd", explicit)
     if choice != "auto":
         return choice
-    jax = sys.modules.get("jax")
-    if jax is not None and jax.default_backend() == "tpu":
-        return "pallas"
-    return "host"
+    import jax
+
+    return "pallas" if jax.default_backend() == "tpu" else "host"
 
 
 def replica_deletion_auto(problem: AssignmentProblem, seed: int = 0) -> Assignment:

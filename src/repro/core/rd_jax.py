@@ -29,21 +29,22 @@ copy) sort by the strip key ``(-count, alt, holders-row, group, slot)``
 comparing holder rows lexicographically *is* the reference's sorted
 server-tuple order — then a prefix-sum of member counts against the
 quota ``((load-1) mod μ)+1`` yields every class's deletion in one shot,
-and scatters re-home the members (spin-off slots are allocated from a
-bump counter; duplicate ``(group, set)`` slots reached via different
-strip paths are exchangeable under the total key, so no global dict is
-needed).  With ``backend="pallas"`` the sort + prefix walk runs as the
-fused kernel in :mod:`repro.kernels.rd` (bitonic network over the slot
+and scatters re-home the members (spin-off slots are the lowest empty
+slots, so drained classes are recycled; duplicate ``(group, set)``
+slots reached via different strip paths are exchangeable under the
+total key, so no global dict is needed).  With ``backend="pallas"``
+the sort + prefix walk runs as the fused kernel in
+:mod:`repro.kernels.rd` (bitonic network over the slot
 lanes with the multi-row lexicographic key, Hillis–Steele prefix sums —
 the waterlevel kernel's recipe); the surrounding delta updates are
 shared jnp either way, so the two device backends are permutation-
 identical by construction.
 
-Slot capacity ``C`` is fixed per dispatch (power-of-two padded, bounded
-by ``K + Σ_k size_k·(|S_k|-1)`` — one new class per member-deleting
-move is the worst case).  If the generous default cap is ever exceeded
-the program sets an ``overflow`` flag and the host adapter re-runs the
-instance through host RD, so results stay correct for any input.
+Slot capacity ``C`` is fixed per dispatch (power-of-two padded) and
+sized so it cannot run out (:func:`rd_slot_capacity`).  Should a
+smaller capacity ever be exceeded, the program sets an ``overflow`` flag
+and the host adapter re-runs the instance through host RD, so results
+stay correct for any input.
 
 Every backend is *assignment-identical* to the executable specification
 in :mod:`repro.core.rd_reference` under the documented deterministic
@@ -99,19 +100,23 @@ def _ceil_div(a: jax.Array, b: jax.Array) -> jax.Array:
 def rd_slot_capacity(problem: AssignmentProblem) -> int:
     """Slot capacity ``C`` for one instance (power of two, ≥128 lanes).
 
-    Every move event (one class losing members to one spin-off) creates
-    at most one slot and deletes at least one replica, so distinct slots
-    are bounded by ``K + Σ_k size_k·(|S_k|-1)``.  The practical count is
-    far smaller (a few × K·A at paper scale), so the cap is the *minimum*
-    of the hard bound and a generous heuristic — the heuristic keeps the
-    dense state small, the ``overflow`` flag + host fallback keeps the
-    rare blowout correct.
+    Two bounds on the slots a strip can need, whichever is smaller:
+
+    - every move event (one class losing members to one spin-off)
+      creates at most one slot and deletes at least one replica, so
+      ``K + Σ_k size_k·(|S_k|-1)`` slots are ever allocated;
+    - drained slots are recycled, so the slots in use are the live
+      classes — each holds at least one of the ``n`` tasks — plus the
+      spin-offs of the strip in flight, at most one per moving class and
+      so at most the quota ``≤ max μ``.
+
+    The capacity therefore never overflows; the ``overflow`` flag and its
+    host re-run only guard a smaller capacity forced from outside.
     """
     k = len(problem.groups)
-    a_max = max((len(g.servers) for g in problem.groups), default=1)
     hard = k + sum(g.size * (len(g.servers) - 1) for g in problem.groups) + 1
-    heuristic = 32 * k * a_max + 256
-    return max(_MIN_LANES, _next_pow2(min(hard, heuristic)))
+    live = problem.n_tasks + int(np.max(problem.mu, initial=1))
+    return max(_MIN_LANES, _next_pow2(min(hard, live)))
 
 
 def _pack_setkey(holders: jax.Array) -> jax.Array:
@@ -133,7 +138,6 @@ class _RDDev(NamedTuple):
     m1: jax.Array  # (C,) i32 cheapest holder
     b1: jax.Array  # (C,) i32 its initial busy time
     b2: jax.Array  # (C,) i32 second-cheapest initial busy time
-    n_slots: jax.Array  # () i32 bump allocator
     load: jax.Array  # (M,) i32
     multi: jax.Array  # (M,) i32 multi-copy population per server
     busy_est: jax.Array  # (M,) i32  b_m + ceil(load_m/mu_m)
@@ -229,11 +233,16 @@ def _strip(
     jpos = jnp.argmax(is_m, axis=1)  # m's column (valid where onm)
     d_exist = st.dest[rows, jpos]
     need_new = mv & (d_exist < 0)
-    d_new = st.n_slots + jnp.cumsum(need_new) - 1
+    # spin-offs take the lowest free slots: empty, and not the existing
+    # (possibly drained) destination of this strip's moves
+    reused = jnp.zeros(c_slots, bool).at[
+        jnp.where(mv & ~need_new, d_exist, c_slots)
+    ].set(True, mode="drop")
+    free = (st.size == 0) & ~reused
+    (free_ids,) = jnp.nonzero(free, size=c_slots, fill_value=c_slots)
+    d_new = free_ids[jnp.clip(jnp.cumsum(need_new) - 1, 0, c_slots - 1)]
     d = jnp.where(need_new, d_new, d_exist)
-    created = need_new.sum()
-    overflow = st.overflow | (st.n_slots + created > c_slots)
-    n_slots = jnp.minimum(st.n_slots + created, c_slots)
+    overflow = st.overflow | (need_new.sum() > free.sum())
 
     # spun holder row: drop the (unique) entry equal to m, shift left
     shifted = jnp.concatenate(
@@ -251,7 +260,12 @@ def _strip(
     m1 = st.m1.at[tgt_new].set(nm1, mode="drop")
     b1 = st.b1.at[tgt_new].set(nb1, mode="drop")
     b2 = st.b2.at[tgt_new].set(nb2, mode="drop")
-    dest = st.dest.at[jnp.where(mv, rows, c_slots), jpos].set(d, mode="drop")
+    # a recycled slot starts a new class: pointers into it from earlier
+    # classes, and its own spin-off pointers, are stale
+    fresh = jnp.zeros(c_slots + 1, bool).at[tgt_new].set(True)
+    dest = jnp.where(fresh[jnp.where(st.dest < 0, c_slots, st.dest)], -1, st.dest)
+    dest = dest.at[tgt_new].set(-1, mode="drop")
+    dest = dest.at[jnp.where(mv, rows, c_slots), jpos].set(d, mode="drop")
 
     tgt_mv = jnp.where(mv, d, c_slots)
     size = (st.size - take).at[tgt_mv].add(take, mode="drop")
@@ -276,7 +290,6 @@ def _strip(
             m1=m1,
             b1=b1,
             b2=b2,
-            n_slots=n_slots,
             load=load,
             multi=multi,
             busy_est=busy_est,
@@ -311,7 +324,6 @@ def _rd_core(
     size0: jax.Array,
     cnt0: jax.Array,
     grp0: jax.Array,
-    n0: jax.Array,
     *,
     use_pallas: bool,
     interpret: bool,
@@ -340,7 +352,6 @@ def _rd_core(
         m1=m1,
         b1=b1,
         b2=b2,
-        n_slots=n0.astype(jnp.int32),
         load=load,
         multi=multi,
         busy_est=busy0 + _ceil_div(load, mu),
@@ -425,17 +436,17 @@ def _rd_core(
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def _rd_device(busy0, mu, holders0, size0, cnt0, grp0, n0, *, use_pallas,
+def _rd_device(busy0, mu, holders0, size0, cnt0, grp0, *, use_pallas,
                interpret):
     st = _rd_core(
-        busy0, mu, holders0, size0, cnt0, grp0, n0,
+        busy0, mu, holders0, size0, cnt0, grp0,
         use_pallas=use_pallas, interpret=interpret,
     )
     return st.size, st.cnt, st.grp, st.holders[:, 0], st.overflow
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def _rd_device_chain(busy0, mu, holders0, size0, cnt0, grp0, n0, *,
+def _rd_device_chain(busy0, mu, holders0, size0, cnt0, grp0, *,
                      use_pallas, interpret):
     """Sequential admission of B jobs in one scan, carrying busy levels.
 
@@ -447,9 +458,9 @@ def _rd_device_chain(busy0, mu, holders0, size0, cnt0, grp0, n0, *,
     m_servers = busy0.shape[0]
 
     def job_step(busy, inp):
-        h0, s0, c0, g0, nn, mu_j = inp
+        h0, s0, c0, g0, mu_j = inp
         st = _rd_core(
-            busy, mu_j, h0, s0, c0, g0, nn,
+            busy, mu_j, h0, s0, c0, g0,
             use_pallas=use_pallas, interpret=interpret,
         )
         loads = (
@@ -466,14 +477,14 @@ def _rd_device_chain(busy0, mu, holders0, size0, cnt0, grp0, n0, *,
     _, outs = jax.lax.scan(
         job_step,
         busy0.astype(jnp.int32),
-        (holders0, size0, cnt0, grp0, n0, mu),
+        (holders0, size0, cnt0, grp0, mu),
     )
     return outs
 
 
 def _dense_instance(
     problem: AssignmentProblem, c_cap: int, a_pad: int
-) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, int]:
+) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """Initial slot arrays: one slot per task group, padded to (C, A)."""
     m = problem.n_servers
     holders = np.full((c_cap, a_pad), m, dtype=np.int32)
@@ -485,7 +496,7 @@ def _dense_instance(
         size[k] = g.size
         cnt[k] = len(g.servers)
         grp[k] = k
-    return holders, size, cnt, grp, len(problem.groups)
+    return holders, size, cnt, grp
 
 
 def _decode(
@@ -627,7 +638,6 @@ def _rd_abstract(geom: dict):
             sd((b_pad, c_cap), i32),
             sd((b_pad, c_cap), i32),
             sd((b_pad, c_cap), i32),
-            sd((b_pad,), i32),
         )
     fn = functools.partial(_rd_device, use_pallas=use_pallas, interpret=True)
     return fn, (
@@ -637,7 +647,6 @@ def _rd_abstract(geom: dict):
         sd((c_cap,), i32),
         sd((c_cap,), i32),
         sd((c_cap,), i32),
-        sd((), i32),
     )
 
 
@@ -695,7 +704,8 @@ def replica_deletion_jax(
         max(2, max((len(g.servers) for g in problem.groups), default=1))
     )
     use_pallas, interpret = _resolve_device(backend, c_cap, a_pad)
-    holders, size, cnt, grp, n0 = _dense_instance(problem, c_cap, a_pad)
+    downgrade = backend == "pallas" and not use_pallas
+    holders, size, cnt, grp = _dense_instance(problem, c_cap, a_pad)
     prof = _obs_device()
     t0 = prof.start() if prof is not None else 0.0
     size_f, cnt_f, grp_f, srv_f, overflow = _rd_device(
@@ -705,21 +715,23 @@ def replica_deletion_jax(
         jnp.asarray(size),
         jnp.asarray(cnt),
         jnp.asarray(grp),
-        jnp.asarray(n0, jnp.int32),
         use_pallas=use_pallas,
         interpret=interpret,
     )
-    if bool(overflow):  # rare: slot heuristic exceeded — host re-run
+    if bool(overflow):  # only under a capacity forced smaller — host re-run
         if prof is not None:
             prof.record(
                 "rd-device", (problem.n_servers, c_cap, a_pad), t0,
-                fallback=True,
+                fallback=True, downgrade=downgrade,
             )
         return replica_deletion(problem)
     size_f, cnt_f = np.asarray(size_f), np.asarray(cnt_f)
     grp_f, srv_f = np.asarray(grp_f), np.asarray(srv_f)
     if prof is not None:  # past the host sync; sig = the kernelcheck key
-        prof.record("rd-device", (problem.n_servers, c_cap, a_pad), t0)
+        prof.record(
+            "rd-device", (problem.n_servers, c_cap, a_pad), t0,
+            downgrade=downgrade,
+        )
     return _decode(problem, size_f, cnt_f, grp_f, srv_f)
 
 
@@ -794,15 +806,15 @@ def replica_deletion_jax_chain(
         )
     )
     use_pallas, interpret = _resolve_device(backend, c_cap, a_pad)
+    downgrade = backend == "pallas" and not use_pallas
     b_pad = _next_pow2(len(problems))
     holders = np.full((b_pad, c_cap, a_pad), m, dtype=np.int32)
     size = np.zeros((b_pad, c_cap), dtype=np.int32)
     cnt = np.zeros((b_pad, c_cap), dtype=np.int32)
     grp = np.zeros((b_pad, c_cap), dtype=np.int32)
-    n0 = np.zeros(b_pad, dtype=np.int32)
     mu = np.ones((b_pad, m), dtype=np.int32)
     for i, p in enumerate(problems):
-        holders[i], size[i], cnt[i], grp[i], n0[i] = _dense_instance(
+        holders[i], size[i], cnt[i], grp[i] = _dense_instance(
             p, c_cap, a_pad
         )
         mu[i] = p.mu
@@ -815,7 +827,6 @@ def replica_deletion_jax_chain(
         jnp.asarray(size),
         jnp.asarray(cnt),
         jnp.asarray(grp),
-        jnp.asarray(n0),
         use_pallas=use_pallas,
         interpret=interpret,
     )
@@ -827,7 +838,8 @@ def replica_deletion_jax_chain(
 
         if prof is not None:
             prof.record(
-                "rd-chain", (m, c_cap, a_pad, b_pad), t0, fallback=True
+                "rd-chain", (m, c_cap, a_pad, b_pad), t0,
+                fallback=True, downgrade=downgrade,
             )
         return host_commit_walk(problems)
     from .reorder import commit_busy
@@ -837,7 +849,9 @@ def replica_deletion_jax_chain(
     grp_f = np.asarray(grp_f)
     srv_f = np.asarray(srv_f)
     if prof is not None:  # past the host sync; sig = the kernelcheck key
-        prof.record("rd-chain", (m, c_cap, a_pad, b_pad), t0)
+        prof.record(
+            "rd-chain", (m, c_cap, a_pad, b_pad), t0, downgrade=downgrade
+        )
     busy = np.asarray(base)
     out: list[Assignment] = []
     for i, p in enumerate(problems):

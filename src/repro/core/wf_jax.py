@@ -52,17 +52,20 @@ def _ceil_div(a: jax.Array, b: jax.Array) -> jax.Array:
     return -(-a // b)
 
 
-def _resolve_pallas(use_pallas: bool | None, m: int) -> bool:
-    """Static backend choice for an M-server water level.
+def _resolve_pallas(use_pallas: bool | None, m: int) -> tuple[bool, bool]:
+    """Static backend choice for an M-server water level: ``(use the
+    kernel, downgraded)``.
 
-    ``None`` → auto (Pallas on TPU, jnp elsewhere); see
-    :func:`repro.kernels.waterlevel.resolve_use_pallas`.  Imported lazily
+    ``None`` → auto (Pallas on TPU, jnp elsewhere); ``downgraded`` marks
+    a kernel request past its width bound, counted by the device
+    profiler as a ``pallas_downgrade``.  See
+    :func:`repro.kernels.waterlevel.pallas_dispatch`.  Imported lazily
     (and :mod:`repro.kernels` exports lazily) so the first call pays only
     the waterlevel-module import, not the whole kernels package.
     """
-    from repro.kernels.waterlevel import resolve_use_pallas
+    from repro.kernels.waterlevel import pallas_dispatch
 
-    return resolve_use_pallas(use_pallas, m)
+    return pallas_dispatch(use_pallas, m)
 
 
 # ---------------------------------------------------------------------------
@@ -80,7 +83,7 @@ def _wf_dispatch(geom: dict) -> str:
     from repro import backend as backend_config
 
     with backend_config.set_backend(waterlevel=geom["requested"]):
-        return "pallas" if _resolve_pallas(None, geom["m"]) else "jnp"
+        return "pallas" if _resolve_pallas(None, geom["m"])[0] else "jnp"
 
 
 def _wf_vmem(geom: dict):
@@ -189,7 +192,7 @@ def water_level(
         on TPU, this jnp path otherwise); both produce bit-identical
         levels.
     """
-    if _resolve_pallas(use_pallas, busy.shape[-1]):
+    if _resolve_pallas(use_pallas, busy.shape[-1])[0]:
         from repro.kernels.waterlevel import water_level_pallas
 
         return water_level_pallas(busy, mu, mask, demand)
@@ -226,7 +229,7 @@ def water_fill_alloc(
     ``use_pallas`` (auto on TPU) the sort + prefix sums + segment search
     run as one fused kernel; allocations are bit-identical either way.
     """
-    if _resolve_pallas(use_pallas, busy.shape[-1]):
+    if _resolve_pallas(use_pallas, busy.shape[-1])[0]:
         from repro.kernels.waterlevel import water_fill_alloc_pallas
 
         return water_fill_alloc_pallas(busy, mu, mask, demand)
@@ -265,7 +268,7 @@ def water_fill_groups(
       levels: (K,) int32 water levels ``ξ_k``.
       phi: scalar int32 — ``max_k ξ_k`` over non-empty groups (WF's Φ_c).
     """
-    up = _resolve_pallas(use_pallas, busy.shape[-1])
+    up, _ = _resolve_pallas(use_pallas, busy.shape[-1])
 
     def step(b, inputs):
         m_k, d_k = inputs
@@ -344,7 +347,7 @@ def water_fill_batch(
     a vmapped groups scan; the Pallas path runs each group step as one
     batched-grid kernel call over all B rows — bit-identical results.
     """
-    if _resolve_pallas(use_pallas, busy.shape[-1]):
+    if _resolve_pallas(use_pallas, busy.shape[-1])[0]:
         return _water_fill_groups_batch_pallas(busy, mu, group_mask, demands)
     return _water_fill_batch_vmap(busy, mu, group_mask, demands)
 
@@ -376,7 +379,7 @@ def water_fill_chain(
       phi: (B,) int32 per-job ``Φ_c`` (max water level over its groups).
       busy_out: (M,) int32 busy levels after the whole burst.
     """
-    up = _resolve_pallas(use_pallas, busy.shape[-1])
+    up, _ = _resolve_pallas(use_pallas, busy.shape[-1])
 
     def job_step(b, inputs):
         mu_j, mask_j, d_j = inputs
@@ -503,7 +506,7 @@ def water_filling_jax(
     busy, mu, masks, demands = _dense_inputs([problem], k_pad)
     # resolve before the jit boundary so the cache keys on the
     # concrete backend (set_backend scopes stay effective per call)
-    up = _resolve_pallas(use_pallas, problem.n_servers)
+    up, downgrade = _resolve_pallas(use_pallas, problem.n_servers)
     prof = _obs_device()
     t0 = prof.start() if prof is not None else 0.0
     alloc, _, phi = _wf_groups_jit(
@@ -513,7 +516,10 @@ def water_filling_jax(
     )
     alloc, phi = np.asarray(alloc), int(phi)
     if prof is not None:  # past the host sync; sig = the kernelcheck key
-        prof.record("wf-groups", (problem.n_servers, k_pad, up), t0)
+        prof.record(
+            "wf-groups", (problem.n_servers, k_pad, up), t0,
+            downgrade=downgrade,
+        )
     return _to_assignment(problem, alloc, phi)
 
 
@@ -562,7 +568,7 @@ def water_filling_jax_batch(
     busy, mu, masks, demands = _dense_inputs(problems, k_pad)
     # resolve before the jit boundary so the cache keys on the
     # concrete backend (set_backend scopes stay effective per call)
-    up = _resolve_pallas(use_pallas, m)
+    up, downgrade = _resolve_pallas(use_pallas, m)
     prof = _obs_device()
     t0 = prof.start() if prof is not None else 0.0
     alloc, _, phi = _wf_batch_jit(
@@ -572,7 +578,10 @@ def water_filling_jax_batch(
     alloc = np.asarray(alloc)
     phi = np.asarray(phi)
     if prof is not None:  # past the host sync; sig = the kernelcheck key
-        prof.record("wf-batch", (m, k_pad, up, len(problems)), t0)
+        prof.record(
+            "wf-batch", (m, k_pad, up, len(problems)), t0,
+            downgrade=downgrade,
+        )
     return [
         _to_assignment(p, alloc[i], int(phi[i])) for i, p in enumerate(problems)
     ]
@@ -636,7 +645,7 @@ def water_filling_jax_chain(
         mu = np.concatenate([mu, np.ones((pad, m), np.int32)])
         masks = np.concatenate([masks, np.zeros((pad, k_pad, m), bool)])
         demands = np.concatenate([demands, np.zeros((pad, k_pad), np.int32)])
-    up = _resolve_pallas(use_pallas, m)
+    up, downgrade = _resolve_pallas(use_pallas, m)
     prof = _obs_device()
     t0 = prof.start() if prof is not None else 0.0
     alloc, phi, _ = _wf_chain_jit(
@@ -646,7 +655,10 @@ def water_filling_jax_chain(
     alloc = np.asarray(alloc)
     phi = np.asarray(phi)
     if prof is not None:  # past the host sync; sig = the kernelcheck key
-        prof.record("wf-chain", (m, k_pad, up, b_pad), t0)
+        prof.record(
+            "wf-chain", (m, k_pad, up, b_pad), t0,
+            downgrade=downgrade,
+        )
     return [
         _to_assignment(p, alloc[i], int(phi[i])) for i, p in enumerate(problems)
     ]

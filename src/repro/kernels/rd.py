@@ -50,7 +50,13 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.analysis.contracts import Axis, contract
 
 # shared plumbing: stage tables, prefix scan, interpret resolution
-from .waterlevel import _bitonic_stages, _interp, _scan_sum
+from .waterlevel import (
+    _bitonic_stages,
+    _butterfly,
+    _compiler_params,
+    _interp,
+    _scan_sum,
+)
 
 __all__ = [
     "RD_PALLAS_MAX_C",
@@ -64,7 +70,7 @@ _BIG = 2**30  # must match repro.core.rd_jax._BIG (non-candidate sentinel)
 # single-block VMEM bounds: the (R, C) key block plus sort temporaries
 # must stay resident, so cap the slot lanes and the key rows (R = P + 3:
 # -count, alt, the P packed holder words, group)
-RD_PALLAS_MAX_C = 1 << 14
+RD_PALLAS_MAX_C = 1 << 15
 RD_PALLAS_MAX_KEY_ROWS = 24
 
 
@@ -90,15 +96,25 @@ def _rd_strip_dispatch(geom: dict) -> str:
 
 
 def _rd_strip_vmem(geom: dict) -> dict[str, tuple[tuple[int, ...], int]]:
+    """Per-invocation VMEM blocks, as :func:`repro.kernels.waterlevel.
+    wl_vmem_blocks` counts them: kernelcheck pads each ``(rows, c)``
+    block and each ``(1, c)`` row to whole (8, 128) tiles.  The key block
+    is carried like the row arrays (two rotations and a select per
+    stage), and the lexicographic compare slices one ``(1, c)`` row per
+    key row out of the block and its partner."""
     c, rows = geom["c"], geom["rows"]
+    keys = ((rows, c), 4)
     return {
-        "keys/in": ((rows, c), 4),
+        "keys/in": keys,
         "size/in": ((1, c), 4),
         "take/out": ((1, c), 4),
         "idx/out": ((1, c), 4),
-        "sort carries (keys,size,idx)": ((rows + 2, c), 4),
-        "partner rolls (keys,size,idx)": ((rows + 2, c), 4),
-        "scan temporaries (prefix,prev)": ((2, c), 4),
+        "sort carry (keys)": keys,
+        "sort carries (size,idx)": ((2, 1, c), 4),
+        "stage rotations + selects (keys)": ((3, rows, c), 4),
+        "stage rotations + selects (size,idx)": ((6, 1, c), 4),
+        "compare row slices": ((rows, 1, c), 4),
+        "scan temporaries (prefix,prev)": ((2, 1, c), 4),
     }
 
 
@@ -155,9 +171,9 @@ def _rd_strip_kernel(
         kb, sz, idx = carry
         k, j = ktab_ref[s], jtab_ref[s]
         lower = (lane & j) == 0
-        kb_p = jnp.where(lower, jnp.roll(kb, -j, axis=1), jnp.roll(kb, j, axis=1))
-        sz_p = jnp.where(lower, jnp.roll(sz, -j, axis=1), jnp.roll(sz, j, axis=1))
-        i_p = jnp.where(lower, jnp.roll(idx, -j, axis=1), jnp.roll(idx, j, axis=1))
+        kb_p = _butterfly(kb, lower, j, n_lanes)
+        sz_p = _butterfly(sz, lower, j, n_lanes)
+        i_p = _butterfly(idx, lower, j, n_lanes)
         # lexicographic compare over the key rows, lane index last
         gt = jnp.zeros((1, n_lanes), jnp.bool_)
         eq = jnp.ones((1, n_lanes), jnp.bool_)
@@ -213,6 +229,7 @@ def _rd_strip_call(
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(
         quota.astype(jnp.int32).reshape(1, 1),

@@ -53,7 +53,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.analysis.contracts import Interval, RangeClaim, choice, contract, span
+from repro.analysis.contracts import (
+    VMEM_LIMIT_BYTES,
+    Interval,
+    RangeClaim,
+    choice,
+    contract,
+    span,
+)
 
 __all__ = [
     "PALLAS_MAX_M",
@@ -64,6 +71,7 @@ __all__ = [
     "WL_MU_MAX",
     "WL_SUM_BMU_MAX",
     "WL_TOTAL_DEMAND_MAX",
+    "pallas_dispatch",
     "resolve_use_pallas",
     "water_level_pallas",
     "water_fill_alloc_pallas",
@@ -75,8 +83,8 @@ _BIG = 2**30
 
 _LANES = 128  # TPU lane width: minimum padded M
 
-# VMEM bound for the single-block kernel: a handful of (1, M) int32
-# arrays plus scan temporaries stay well under 16 MB up to 2^15 lanes.
+# Widest single-block kernel: its (1, M) int32 rows and their stage and
+# scan temporaries must stay resident in VMEM (wl_vmem_blocks).
 PALLAS_MAX_M = 1 << 15
 
 
@@ -96,18 +104,27 @@ def resolve_use_pallas(explicit: bool | None, m: int) -> bool:
     :data:`PALLAS_MAX_M` always fall back to jnp (the single-block
     kernel would not fit VMEM).
     """
+    return pallas_dispatch(explicit, m)[0]
+
+
+def pallas_dispatch(explicit: bool | None, m: int) -> tuple[bool, bool]:
+    """``(use the kernel, downgraded)`` for a width-``m`` problem, resolved
+    once: ``downgraded`` is True when the request resolves to the kernel
+    but ``m`` is past :data:`PALLAS_MAX_M`, so the jnp pipeline runs
+    instead — the event the adapters count as
+    ``device.<kind>.pallas_downgrade``."""
     from repro import backend as backend_config
 
-    if m > PALLAS_MAX_M:
-        return False
     if explicit is not None:
-        return bool(explicit)
-    choice = backend_config.resolve("waterlevel")
-    if choice == "jnp":
-        return False
-    if choice == "pallas":
-        return True
-    return jax.default_backend() == "tpu"
+        requested = bool(explicit)
+    else:
+        configured = backend_config.resolve("waterlevel")
+        if configured == "auto":
+            requested = jax.default_backend() == "tpu"
+        else:
+            requested = configured == "pallas"
+    use = requested and m <= PALLAS_MAX_M
+    return use, requested and not use
 
 
 # ---------------------------------------------------------------------------
@@ -180,7 +197,14 @@ def wl_range_claims(m: int) -> list[RangeClaim]:
 def wl_vmem_blocks(geom: dict) -> dict[str, tuple[tuple[int, ...], int]]:
     """Per-invocation VMEM blocks at the padded lane count: kernel
     operands/outputs plus the live scan/sort temporaries (the batch grid
-    hands each program the same one-row view)."""
+    hands each program the same one-row view).
+
+    Each entry is a stack of separate ``(1, lanes)`` rows; kernelcheck
+    pads every row to a whole (8, 128) tile as the TPU lays it out.  The
+    stage temporaries (each carry's two lane rotations and their select)
+    make the model an upper bound of what the TPU compiler asks for:
+    ``tests/test_chip_compile.py`` compiles the kernel with exactly this
+    much scoped VMEM."""
     lanes = _wl_lanes(geom["m"])
     row = ((1, lanes), 4)
     return {
@@ -188,46 +212,49 @@ def wl_vmem_blocks(geom: dict) -> dict[str, tuple[tuple[int, ...], int]]:
         "mu/in": row,
         "take/out": row,
         "idx/out": row,
-        "sort carries (b,w,idx)": ((3, lanes), 4),
-        "partner rolls (b,w,idx)": ((3, lanes), 4),
-        "scan temporaries (cw,cbw,caps,prev)": ((4, lanes), 4),
+        "sort carries (b,w,idx)": ((3, 1, lanes), 4),
+        "stage rotations + selects (b,w,idx)": ((9, 1, lanes), 4),
+        "scan temporaries (cw,cbw,caps,prev)": ((4, 1, lanes), 4),
     }
 
 
 def _wl_abstract(geom: dict):
     lanes = _wl_lanes(geom["m"])
+    bsz = geom.get("b", 1)
     i32 = jnp.int32
     fn = functools.partial(_waterlevel_call_padded, interpret=True)
     return fn, (
-        jax.ShapeDtypeStruct((1, lanes), i32),
-        jax.ShapeDtypeStruct((1, lanes), i32),
-        jax.ShapeDtypeStruct((1, 1), i32),
-    )
-
-
-def _wl_batch_abstract(geom: dict):
-    lanes = _wl_lanes(geom["m"])
-    bsz = geom["b"]
-    i32 = jnp.int32
-    fn = functools.partial(_waterlevel_call_padded_batch, interpret=True)
-    return fn, (
         jax.ShapeDtypeStruct((bsz, lanes), i32),
         jax.ShapeDtypeStruct((bsz, lanes), i32),
-        jax.ShapeDtypeStruct((bsz, 1), i32),
+        jax.ShapeDtypeStruct((bsz,), i32),
     )
 
 
 def _scan_sum(x: jax.Array, lane: jax.Array, n: int) -> jax.Array:
     """Inclusive prefix sum along lanes (Hillis–Steele, log2(n) steps).
 
-    ``jnp.roll`` wraps, but wrapped lanes (lane < d) are masked to 0, so
-    the scan is exact for any values.
+    The lane rotation wraps, but wrapped lanes (lane < d) are masked to
+    0, so the scan is exact for any values.
     """
     d = 1
     while d < n:
-        x = x + jnp.where(lane >= d, jnp.roll(x, d, axis=1), 0)
+        x = x + jnp.where(lane >= d, pltpu.roll(x, d, 1), 0)
         d *= 2
     return x
+
+
+def _compiler_params() -> pltpu.CompilerParams:
+    """Mosaic parameters shared by the single-block kernels (read at
+    trace time, so the scoped VMEM follows :data:`VMEM_LIMIT_BYTES`)."""
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def _butterfly(x: jax.Array, lower: jax.Array, j: jax.Array, n: int) -> jax.Array:
+    """Partner lanes of one compare-exchange stage: ``x`` rotated ``j``
+    lanes left where ``lower``, else ``j`` lanes right.  The TPU lane
+    rotation takes only non-negative shifts, so left by ``j`` is right by
+    ``n - j``."""
+    return jnp.where(lower, pltpu.roll(x, n - j, 1), pltpu.roll(x, j, 1))
 
 
 @functools.lru_cache(maxsize=None)
@@ -250,7 +277,9 @@ def _waterlevel_kernel(
     demand_ref, ktab_ref, jtab_ref, b_ref, w_ref, level_ref, take_ref, idx_ref,
     *, n_lanes: int, n_stages: int,
 ):
-    """Fused water level + allocation over one (1, n_lanes) block.
+    """Fused water level + allocation over one (1, n_lanes) row block
+    (grid program ``i`` reads ``demand_ref[i]`` and writes
+    ``level_ref[i]``).
 
     Inputs are pre-masked: ``b = busy`` where available else ``_BIG``,
     ``w = μ`` where available else 0; padded lanes carry the same
@@ -274,9 +303,9 @@ def _waterlevel_kernel(
         b, w, idx = carry
         k, j = ktab_ref[s], jtab_ref[s]
         lower = (lane & j) == 0
-        b_p = jnp.where(lower, jnp.roll(b, -j, axis=1), jnp.roll(b, j, axis=1))
-        w_p = jnp.where(lower, jnp.roll(w, -j, axis=1), jnp.roll(w, j, axis=1))
-        i_p = jnp.where(lower, jnp.roll(idx, -j, axis=1), jnp.roll(idx, j, axis=1))
+        b_p = _butterfly(b, lower, j, n_lanes)
+        w_p = _butterfly(w, lower, j, n_lanes)
+        i_p = _butterfly(idx, lower, j, n_lanes)
         asc = (lane & k) == 0
         gt = (b > b_p) | ((b == b_p) & (idx > i_p))
         # a lane keeps the pair's min iff it is the lower lane of an
@@ -291,11 +320,12 @@ def _waterlevel_kernel(
     b, w, idx = jax.lax.fori_loop(0, n_stages, stage, (b, w, idx))
 
     # --- prefix sums + masked ceiling-division segment search ------------
-    demand = demand_ref[0, 0]
+    row = pl.program_id(0)
+    demand = demand_ref[row]
     cw = _scan_sum(w, lane, n_lanes)
     cbw = _scan_sum(b * w, lane, n_lanes)
     xi = -(-(demand + cbw) // jnp.maximum(cw, 1))
-    next_b = jnp.where(lane == n_lanes - 1, _BIG, jnp.roll(b, -1, axis=1))
+    next_b = jnp.where(lane == n_lanes - 1, _BIG, pltpu.roll(b, n_lanes - 1, 1))
     valid = (xi <= next_b) & (cw > 0)
     # first valid segment, with the jnp path's argmax convention (0 when
     # nothing is valid — the guarded-degenerate case)
@@ -305,7 +335,7 @@ def _waterlevel_kernel(
     xi0 = jnp.sum(jnp.where(sel, xi, 0))  # exactly one selected lane
     b0 = jnp.sum(jnp.where(sel, b, 0))
     level = jnp.maximum(xi0, b0 + 1)
-    level_ref[0, 0] = level
+    level_ref[row] = level
 
     # --- allocation at the level (Alg. 2 lines 7-13, prefix-sum clamp) ---
     caps = jnp.maximum(level - b, 0) * w
@@ -316,104 +346,74 @@ def _waterlevel_kernel(
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _waterlevel_call_padded(
-    b2: jax.Array, w2: jax.Array, d2: jax.Array, *, interpret: bool
+    b2: jax.Array, w2: jax.Array, d: jax.Array, *, interpret: bool
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Invoke the kernel on already-padded ``(1, n_lanes)`` inputs.
+    """Invoke the kernel on already-padded ``(B, n_lanes)`` rows.
+
+    ``d`` holds the ``(B,)`` demands.  The grid's ``B`` programs each see
+    one ``(1, n_lanes)`` row block and read their demand (and write their
+    level) at ``program_id`` in whole-array SMEM vectors, so every row is
+    bit-identical to a one-row call (and hence to the jnp path).  The
+    stage tables are whole-array SMEM inputs shared by all programs.
 
     Kept separate from the padding so the jit cache keys on the padded
     lane count, not the caller's ``M`` — every ``M ≤ 128`` shares one
     compile instead of recompiling the kernel per distinct width.
     """
-    n_lanes = b2.shape[-1]
+    bsz, n_lanes = b2.shape
     ks, js = _bitonic_stages(n_lanes)
-    level, take, idx = pl.pallas_call(
-        functools.partial(
-            _waterlevel_kernel, n_lanes=n_lanes, n_stages=len(ks)
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-            jax.ShapeDtypeStruct((1, n_lanes), jnp.int32),
-            jax.ShapeDtypeStruct((1, n_lanes), jnp.int32),
-        ],
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
-        interpret=interpret,
-    )(d2, jnp.asarray(ks), jnp.asarray(js), b2, w2)
-    return level[0, 0], take[0], idx[0]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _waterlevel_call_padded_batch(
-    b3: jax.Array, w3: jax.Array, d3: jax.Array, *, interpret: bool
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Batched-grid twin of :func:`_waterlevel_call_padded`.
-
-    ``b3``/``w3`` are ``(B, n_lanes)`` pre-masked rows, ``d3`` is
-    ``(B, 1)`` demands; the kernel body is *unchanged* — the grid's
-    ``B`` programs each see one ``(1, n_lanes)`` block, so every row is
-    bit-identical to the single-problem call (and hence to the jnp
-    path).  The stage tables stay whole-array SMEM inputs shared by all
-    programs.
-    """
-    bsz, n_lanes = b3.shape
-    ks, js = _bitonic_stages(n_lanes)
+    # (B, 1, n_lanes) with the batch dim squeezed: each block's last two
+    # dims equal the array's, which the TPU block-shape rule admits
     row_spec = pl.BlockSpec(
-        (1, n_lanes), lambda b: (b, 0), memory_space=pltpu.VMEM
+        (None, 1, n_lanes), lambda i: (i, 0, 0), memory_space=pltpu.VMEM
     )
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     level, take, idx = pl.pallas_call(
         functools.partial(
             _waterlevel_kernel, n_lanes=n_lanes, n_stages=len(ks)
         ),
         grid=(bsz,),
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, 1), jnp.int32),
-            jax.ShapeDtypeStruct((bsz, n_lanes), jnp.int32),
-            jax.ShapeDtypeStruct((bsz, n_lanes), jnp.int32),
+            jax.ShapeDtypeStruct((bsz,), jnp.int32),
+            jax.ShapeDtypeStruct((bsz, 1, n_lanes), jnp.int32),
+            jax.ShapeDtypeStruct((bsz, 1, n_lanes), jnp.int32),
         ],
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            row_spec,
-            row_spec,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda b: (b, 0), memory_space=pltpu.SMEM),
-            row_spec,
-            row_spec,
-        ],
+        in_specs=[smem, smem, smem, row_spec, row_spec],
+        out_specs=[smem, row_spec, row_spec],
+        compiler_params=_compiler_params(),
         interpret=interpret,
-    )(d3, jnp.asarray(ks), jnp.asarray(js), b3, w3)
-    return level[:, 0], take, idx
+    )(
+        d.astype(jnp.int32),
+        jnp.asarray(ks),
+        jnp.asarray(js),
+        b2.reshape(bsz, 1, n_lanes),
+        w2.reshape(bsz, 1, n_lanes),
+    )
+    return level, take[:, 0], idx[:, 0]
+
+
+def _pad_lanes(b: jax.Array, w: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Pad the last axis to a power of two (≥ the 128-lane width) with the
+    masked-lane sentinels."""
+    m = b.shape[-1]
+    pad = [(0, 0)] * (b.ndim - 1) + [(0, max(_LANES, _next_pow2(m)) - m)]
+    return jnp.pad(b, pad, constant_values=_BIG), jnp.pad(w, pad)
 
 
 def _waterlevel_call(
     b: jax.Array, w: jax.Array, demand: jax.Array, *, interpret: bool
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Pad to a power of two (≥ the 128-lane width) and invoke the kernel.
+    """Pad one problem and invoke the kernel as a one-row grid.
 
     Returns ``(level, take_sorted, idx_sorted)``; the caller scatters the
     sorted takes back through the permutation (padded lanes carry
     out-of-range indices and zero takes, so a ``mode="drop"`` scatter
     ignores them).
     """
-    m = b.shape[0]
-    n_lanes = max(_LANES, _next_pow2(m))
-    pad = n_lanes - m
-    b2 = jnp.pad(b, (0, pad), constant_values=_BIG).reshape(1, n_lanes)
-    w2 = jnp.pad(w, (0, pad)).reshape(1, n_lanes)
-    d2 = jnp.asarray(demand, jnp.int32).reshape(1, 1)
-    return _waterlevel_call_padded(b2, w2, d2, interpret=interpret)
+    b2, w2 = _pad_lanes(b[None], w[None])
+    d = jnp.asarray(demand, jnp.int32).reshape(1)
+    level, take, idx = _waterlevel_call_padded(b2, w2, d, interpret=interpret)
+    return level[0], take[0], idx[0]
 
 
 def _masked_inputs(
@@ -513,7 +513,7 @@ def water_fill_alloc_pallas(
     ranges=lambda geom: wl_range_claims(geom["m"]),
     signature=lambda geom: ("waterlevel-batch", geom["b"], _wl_lanes(geom["m"])),
     max_signatures=32,  # burst-size values × pow2 lane classes
-    abstract=_wl_batch_abstract,
+    abstract=_wl_abstract,
     eval_points=3,
     notes="batched-grid twin; B enters the jit cache unpadded here — "
     "the wf_jax chain adapter pads it, the plain batch adapter keys "
@@ -538,13 +538,9 @@ def water_fill_alloc_pallas_batch(
     b, w = _masked_inputs(busy, mu, mask)
     demand = jnp.asarray(demand, jnp.int32)
     bsz, m = b.shape
-    n_lanes = max(_LANES, _next_pow2(m))
-    pad = n_lanes - m
-    b3 = jnp.pad(b, ((0, 0), (0, pad)), constant_values=_BIG)
-    w3 = jnp.pad(w, ((0, 0), (0, pad)))
-    d3 = demand.reshape(bsz, 1)
-    level, take, idx = _waterlevel_call_padded_batch(
-        b3, w3, d3, interpret=_interp(interpret)
+    b2, w2 = _pad_lanes(b, w)
+    level, take, idx = _waterlevel_call_padded(
+        b2, w2, demand.reshape(bsz), interpret=_interp(interpret)
     )
     rows = jnp.arange(bsz)[:, None]
     alloc = jnp.zeros((bsz, m), jnp.int32).at[rows, idx].set(take, mode="drop")

@@ -16,7 +16,6 @@ from repro.configs.shapes import SHAPES, applicable, get_shape  # noqa: E402
 from repro.launch.hlo import collective_stats  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.specs import cache_specs, input_specs, step_fn_for  # noqa: E402
-from repro.parallel import compat
 from repro.parallel import (  # noqa: E402
     batch_sharding,
     cache_sharding,
@@ -108,7 +107,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str) -> dict:
         # donate the mutable aggregate (train state / decode cache) so the
         # functional update aliases instead of copying
         donate = {"train": (0,), "prefill": (), "decode": (2,)}[shape.kind]
-        with compat.set_mesh(mesh):  # ambient mesh: activation constraints apply
+        with jax.set_mesh(mesh):  # ambient mesh: activation constraints apply
             lowered = jax.jit(
                 fn, in_shardings=in_sh, donate_argnums=donate
             ).lower(*args)

@@ -14,7 +14,6 @@ from repro.configs.shapes import SHAPES, applicable, get_shape  # noqa: E402
 from repro.launch.hlo import collective_stats  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.models import ModelConfig  # noqa: E402
-from repro.parallel import compat  # noqa: E402
 from repro.train import AdamWConfig  # noqa: E402
 
 """Roofline probes: exact per-device FLOPs / bytes / collective traffic.
@@ -129,7 +128,7 @@ def _lower_cell(cfg, shape, *, force_direct: bool, unroll: bool = True):
         )
         step_mod.forward_train = patched
     try:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = jax.jit(fn, in_shardings=in_sh).lower(*args).compile()
         cost = compiled.cost_analysis()
         coll = collective_stats(compiled.as_text())

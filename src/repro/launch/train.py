@@ -18,7 +18,6 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.data import LocalityAwareLoader, ShardStore
 from repro.launch.mesh import make_production_mesh
-from repro.parallel import compat
 from repro.parallel import fsdp_axes, param_sharding
 from repro.train import AdamWConfig, make_train_step, train_state_init
 
@@ -58,7 +57,7 @@ def main(argv=None) -> None:
             in_shardings=(state_sh, None),
             donate_argnums=(0,),
         )
-        ctx = compat.set_mesh(mesh)
+        ctx = jax.set_mesh(mesh)
     else:
         step_fn = jax.jit(
             make_train_step(cfg, opt_cfg, microbatches=args.microbatches),

@@ -27,11 +27,11 @@ token→expert skew differs across data shards.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-from repro.parallel import compat
 
 from .config import ModelConfig
 from .ffn import _positions_in_expert, swiglu
@@ -55,7 +55,8 @@ def moe_apply_sharded(
     wg_spec = P("model", dp, None)
     wo_spec = P("model", None, dp)
 
-    @compat.shard_map(
+    @functools.partial(
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             P(dp, None, None),  # x: tokens on dp, replicated on model

@@ -72,7 +72,10 @@ class DeviceProfiler:
     wall time is attributed to ``compile_us``; subsequent calls with the
     same signature hit the cache and land in ``exec_us``.  Host
     fallbacks (RD capacity overflow) are counted separately — their wall
-    time is genuine scheduling cost, not device time.
+    time is genuine scheduling cost, not device time — and so are Pallas
+    downgrades: a dispatch that resolved to the Pallas kernel but ran
+    the jnp pipeline because its geometry is past the kernel's
+    single-block bounds.
     """
 
     def __init__(self, session: "ObsSession"):
@@ -83,7 +86,13 @@ class DeviceProfiler:
         return clock.perf_counter()
 
     def record(
-        self, kind: str, sig: tuple, t0: float, *, fallback: bool = False
+        self,
+        kind: str,
+        sig: tuple,
+        t0: float,
+        *,
+        fallback: bool = False,
+        downgrade: bool = False,
     ) -> None:
         wall_us = clock.us_since(t0)
         key = (kind, sig)
@@ -100,6 +109,8 @@ class DeviceProfiler:
             m.observe(f"device.{kind}.exec_us", wall_us)
         if fallback:
             m.inc(f"device.{kind}.host_fallback")
+        if downgrade:
+            m.inc(f"device.{kind}.pallas_downgrade")
         trace = s.trace
         if trace is not None:
             trace.record(
@@ -107,7 +118,9 @@ class DeviceProfiler:
                 ts=s.host_us(t0),
                 dur=wall_us,
                 a=trace.intern(f"{kind}{sig}"),
-                b=(1 if miss else 0) | (2 if fallback else 0),
+                b=(1 if miss else 0)
+                | (2 if fallback else 0)
+                | (4 if downgrade else 0),
                 c=wall_us,
             )
 

@@ -46,7 +46,9 @@ INST_DEVICE         host  start us / wall us       str(sig) / flags / ns / -
 
 ``INST_SPEC_RESOLVE.b``: 0 = original copy won, 1 = clone won, 2 = pair
 aborted before completion.  ``INST_DEVICE.b``: bit 0 = jit-cache miss
-(compile included in the wall time), bit 1 = host fallback taken.
+(compile included in the wall time), bit 1 = host fallback taken,
+bit 2 = Pallas requested but the jnp pipeline ran (geometry past the
+kernel's bounds).
 """
 
 from __future__ import annotations
@@ -296,7 +298,10 @@ class TraceRecorder:
                         "ts": ts,
                         "dur": max(dur, 1),
                         "args": dict(
-                            args, cache_miss=bool(b & 1), host_fallback=bool(b & 2)
+                            args,
+                            cache_miss=bool(b & 1),
+                            host_fallback=bool(b & 2),
+                            pallas_downgrade=bool(b & 4),
                         ),
                     }
                 )

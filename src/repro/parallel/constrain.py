@@ -1,7 +1,7 @@
 """Ambient-mesh activation sharding constraints.
 
 Model code calls ``shard(x, "dp", None, "model")`` with *logical* axis
-tags; under ``jax.sharding.use_mesh(mesh)`` (set by the launchers) the
+tags; under ``jax.set_mesh(mesh)`` (set by the launchers) the
 tags resolve to whichever of the mesh axes exist — "dp" → ("pod","data")
 on the multi-pod mesh, ("data",) on a single pod — and a
 ``with_sharding_constraint`` is emitted.  With no ambient mesh (unit
@@ -23,23 +23,11 @@ __all__ = ["shard", "logical_spec", "ambient_mesh"]
 
 
 def ambient_mesh():
-    """The ambient abstract mesh, or None when unset / unsupported.
-
-    ``jax.sharding.get_abstract_mesh`` only exists in newer jax; on older
-    releases the ambient-mesh mechanism is absent entirely, so there is
-    nothing to constrain against and model code falls back to no-op
-    sharding (single-device tests and smoke runs).
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        return None
-    mesh = get()
-    if mesh is None or not getattr(mesh, "axis_names", ()):
-        return None
-    return mesh
-
-
-_ambient_mesh = ambient_mesh  # internal alias kept for call sites below
+    """The ambient abstract mesh (``jax.set_mesh``), or None when unset —
+    model code then skips its sharding hints (single-device tests and
+    smoke runs)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
 
 
 def logical_spec(mesh, *tags) -> P:
@@ -60,7 +48,7 @@ def logical_spec(mesh, *tags) -> P:
 
 def shard(x: jax.Array, *tags) -> jax.Array:
     """Constrain ``x`` to the logical spec if an ambient mesh is set."""
-    mesh = _ambient_mesh()
+    mesh = ambient_mesh()
     if mesh is None:
         return x
     spec = logical_spec(mesh, *tags)

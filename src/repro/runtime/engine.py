@@ -43,7 +43,15 @@ from typing import Callable
 
 import numpy as np
 
-from repro.core import AssignmentProblem, Job, OutstandingJob, TaskGroup
+from repro.core import (
+    AssignmentProblem,
+    Job,
+    OutstandingJob,
+    TaskGroup,
+    replica_deletion_auto,
+    replica_deletion_plus,
+    resolve_rd_backend,
+)
 from repro.obs import clock
 from repro.obs.session import ObsSession, active as obs_active
 from repro.placement import PlacedJob, PlacementEvent, PlacementStore
@@ -164,6 +172,14 @@ class SchedulingEngine:
         self.on_data_loss: Callable[[int, dict[int, int]], bool] | None = None
         self.n_servers = n_servers
         self.policy = make_policy(policy) if isinstance(policy, str) else policy
+        if getattr(self.policy, "assigner", None) in (
+            replica_deletion_auto,
+            replica_deletion_plus,
+        ):
+            # RD's ``auto`` backend imports jax to ask for the platform:
+            # resolve it now, so the import stays out of the first
+            # arrival's timed overhead
+            resolve_rd_backend()
         self.events = tuple(sorted(events, key=lambda e: e.slot))
         self.placement = placement
         if placement is not None and placement.n_servers != n_servers:

@@ -157,6 +157,8 @@ class ControlPlane:
     ):
         scenario_jobs: list[Job] = []
         if scenario is not None:
+            import repro.traces  # noqa: F401  (registers the scenarios)
+
             cfg_cls, gen = registry.resolve("scenario", scenario)
             cfg = cfg_cls(**(scenario_kw or {}))
             scenario_jobs = gen(cfg, store=placement)

@@ -18,13 +18,13 @@ main train step keeps exact bf16 reductions.  Exercised by
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel import compat
 
 __all__ = [
     "quantize_int8",
@@ -74,7 +74,8 @@ def make_compressed_grad_fn(
     dim), and ``err`` has a leading device axis sharded on ``axis``.
     """
 
-    @compat.shard_map(
+    @functools.partial(
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P(axis), P(axis)),
         out_specs=(P(), P(axis)),
@@ -84,7 +85,7 @@ def make_compressed_grad_fn(
         # auto-psum the cotangent of replicated inputs and grad_fn would
         # return the already-summed gradient (8× at 8 devices), defeating
         # the per-device quantization
-        params = jax.tree.map(lambda p: compat.pvary(p, axis), params)
+        params = jax.tree.map(lambda p: jax.lax.pvary(p, axis), params)
         local = grad_fn(params, batch)
         pairs = jax.tree.map(
             lambda g, e: _compress_one(g, e, axis, mesh.shape[axis]),

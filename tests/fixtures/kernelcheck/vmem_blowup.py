@@ -2,8 +2,8 @@
 
 Declares a kernel whose VMEM blocks grow quadratically with the server
 axis — at the admissible ceiling the (m, m) carry block alone is
-64 MiB, four times a TPU core's VMEM.  A correct contract would either
-cap the axis or tile the block; this one does neither, so
+256 MiB, four times the kernels' scoped VMEM.  A correct contract would
+either cap the axis or tile the block; this one does neither, so
 ``python -m repro.analysis.kernelcheck --modules <this file>`` must
 exit 1 with a ``memory`` violation.
 """
@@ -26,7 +26,7 @@ def _vmem(geom):
 
 @contract(
     "fixture.vmem-blowup",
-    axes=(span("m", 128, 4096, boundaries=(1024,)),),
+    axes=(span("m", 128, 8192, boundaries=(1024,)),),
     backends=("pallas",),
     dispatch=_dispatch,
     vmem=_vmem,

@@ -44,7 +44,7 @@ def test_sharded_train_step_matches_single_device():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_smoke_config
         from repro.train import AdamWConfig, make_train_step, train_state_init
-        from repro.parallel import compat
+        from repro.launch.mesh import make_mesh
         from repro.parallel import param_sharding, batch_sharding
 
         cfg = get_smoke_config("qwen1.5-4b")
@@ -57,13 +57,13 @@ def test_sharded_train_step_matches_single_device():
         # single-device reference
         s_ref, m_ref = jax.jit(make_train_step(cfg, opt))(state, batch)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         st_sh = {"params": param_sharding(mesh, state["params"]),
                  "opt": {"m": param_sharding(mesh, state["opt"]["m"]),
                           "v": param_sharding(mesh, state["opt"]["v"]),
                           "step": NamedSharding(mesh, P())}}
         b_sh = batch_sharding(mesh, batch)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             s_dist, m_dist = jax.jit(
                 make_train_step(cfg, opt), in_shardings=(st_sh, b_sh)
             )(state, batch)
@@ -85,19 +85,20 @@ def test_elastic_checkpoint_restore_across_meshes():
         import jax, jax.numpy as jnp, numpy as np, tempfile
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import save_checkpoint, restore_checkpoint
+        from repro.launch.mesh import make_mesh
         from repro.parallel import param_sharding
         from repro.configs import get_smoke_config
         from repro.models import init_params
 
         cfg = get_smoke_config("qwen1.5-4b")
         params = init_params(jax.random.PRNGKey(0), cfg)
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         sh_a = param_sharding(mesh_a, params)
         placed = jax.tree.map(jax.device_put, params, sh_a)
         with tempfile.TemporaryDirectory() as d:
             save_checkpoint(d, 1, placed)
             # restore onto a *different* mesh shape (elastic restart)
-            mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+            mesh_b = make_mesh((2, 4), ("data", "model"))
             sh_b = param_sharding(mesh_b, params)
             restored = restore_checkpoint(d, 1, params, sh_b)
             for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(restored)):
@@ -112,10 +113,11 @@ def test_compressed_grads_match_exact_mean():
     out = _run(
         """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.train.compress import (init_error_state,
                                           make_compressed_grad_fn)
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         w = jnp.zeros((16,))
         rng = np.random.default_rng(0)
         xs = jnp.asarray(rng.normal(size=(64, 16)).astype(np.float32))

@@ -207,6 +207,28 @@ def test_wf_jax_mirror_constants_match_kernels():
     assert wf_jax._WL_M_MAX == waterlevel.WL_M_MAX
 
 
+def test_block_bytes_pad_to_vmem_tiles():
+    from repro.analysis.kernelcheck import _block_bytes
+
+    total, per = _block_bytes(
+        {
+            "row": ((1, 1000), 4),  # one int32 row: 8 sublanes × 1024 lanes
+            "rows": ((3, 1, 128), 4),  # three separate rows
+            "block": ((11, 256), 4),  # 11 sublanes round up to 16
+            "vector": ((128,), 4),  # a 1-D block is one row
+            "bf16": ((9, 128), 2),  # 16 sublanes per bf16 tile
+        }
+    )
+    assert per == {
+        "row": 8 * 1024 * 4,
+        "rows": 3 * 8 * 128 * 4,
+        "block": 16 * 256 * 4,
+        "vector": 8 * 128 * 4,
+        "bf16": 16 * 128 * 2,
+    }
+    assert total == sum(per.values())
+
+
 def test_rd_strip_constants_match_rd_jax():
     """The strip kernel's sentinel and packing width are claimed in both
     contracts; the underlying constants must agree."""

@@ -238,6 +238,41 @@ def test_wf_jax_dispatch_is_profiled():
     assert s.metrics.counter("device.wf-groups.calls") == 1
 
 
+@pytest.mark.parametrize("kind", ["wf-groups", "rd-device"])
+def test_pallas_downgrade_is_counted(monkeypatch, kind):
+    """A dispatch that asked for the Pallas kernel but ran the jnp
+    pipeline (geometry past the kernel's bounds) keeps its result and is
+    counted as a downgrade, not hidden."""
+    from repro.backend import set_backend
+    from repro.kernels import rd as rd_kernel
+    from repro.kernels import waterlevel
+
+    prob = AssignmentProblem(
+        busy=np.array([0, 1, 0, 2], dtype=np.int64),
+        mu=np.ones(4, dtype=np.int64),
+        groups=(TaskGroup(size=3, servers=(0, 1)), TaskGroup(2, (1, 2, 3))),
+    )
+    if kind == "wf-groups":
+        from repro.core.wf_jax import water_filling_jax as assign
+
+        monkeypatch.setattr(waterlevel, "PALLAS_MAX_M", 2)  # below M = 4
+        scope = {"waterlevel": "pallas"}
+    else:
+        from repro.core.rd import replica_deletion_auto as assign
+
+        monkeypatch.setattr(rd_kernel, "rd_pallas_fits", lambda *a: False)
+        scope = {"rd": "pallas"}
+    baseline = assign(prob)
+    with set_backend(**scope), obs.observe() as s:
+        downgraded = assign(prob)
+    assert downgraded.alloc == baseline.alloc
+    assert s.metrics.counter(f"device.{kind}.calls") == 1
+    assert s.metrics.counter(f"device.{kind}.pallas_downgrade") == 1
+    assert s.metrics.counter(f"device.{kind}.host_fallback") == 0
+    (event,) = [r for r in s.trace.records() if r[0] == trace_mod.INST_DEVICE]
+    assert event[4] & 4  # the trace flags the downgrade
+
+
 # ---- schedule invariance (the contract) ------------------------------------
 
 

@@ -23,6 +23,11 @@ kernel's sort order is already pinned to the jnp path by the shared key
 construction (see ``test_kernels.py`` for the kernel-level twin).
 """
 
+import os
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
 import pytest
 
@@ -45,6 +50,10 @@ from repro.core.rd import (
 from repro.core.rd_reference import replica_deletion_reference
 from repro.runtime import SchedulingEngine, make_policy
 from repro.traces import generate
+
+_SRC = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"
+)
 
 
 def _random_instance(rng, m=8, k_hi=4, size_hi=12, avail_hi=4, busy_hi=8):
@@ -177,18 +186,49 @@ def test_empty_problem_matches_host():
 
 def test_overflow_falls_back_to_host(monkeypatch):
     """A slot capacity too small for the instance must flag overflow and
-    transparently re-run on the host path, not return garbage."""
+    re-run on the host path — counted as a host fallback — not return
+    garbage."""
+    from repro import obs
     from repro.core import rd_jax
 
     rng = np.random.default_rng(3)
     problem = _random_instance(rng, m=10, k_hi=4, size_hi=20, avail_hi=6)
-    # barely more slots than initial classes: the first spin-off overflows
-    monkeypatch.setattr(
-        rd_jax, "rd_slot_capacity", lambda p: len(p.groups) + 1
-    )
-    dev = rd_jax.replica_deletion_jax(problem)
+    # one slot per initial class and none spare: the first spin-off
+    # has nowhere to go
+    monkeypatch.setattr(rd_jax, "rd_slot_capacity", lambda p: len(p.groups))
+    host_calls = []
+
+    def _host(p):
+        host_calls.append(p)
+        return replica_deletion(p)
+
+    monkeypatch.setattr(rd_jax, "replica_deletion", _host)
+    with obs.observe(trace=False) as session:
+        dev = rd_jax.replica_deletion_jax(problem)
     ref = replica_deletion_reference(problem)
     assert dev.alloc == ref.alloc
+    assert host_calls == [problem]
+    assert session.metrics.counters["device.rd-device.host_fallback"] == 1
+
+
+def test_recycled_slots_fit_a_heavily_replicated_instance(monkeypatch):
+    """Drained slots are recycled, so the capacity (live tasks plus one
+    strip's spin-offs, 512 here) holds an instance where a fresh slot
+    per move would take 1,784: the device path must finish on its own,
+    without the host re-run."""
+    from repro.core import rd_jax
+
+    problem = AssignmentProblem(
+        busy=np.arange(16, dtype=np.int64) % 3,
+        mu=np.full(16, 3),
+        groups=(
+            TaskGroup(120, tuple(range(12))),
+            TaskGroup(80, tuple(range(4, 12))),
+            TaskGroup(60, tuple(range(4, 16))),
+        ),
+    )
+    assert rd_jax.rd_slot_capacity(problem) == 512
+    _assert_device_matches_reference(problem, "jnp", monkeypatch)
 
 
 def test_backend_resolution_scopes():
@@ -207,6 +247,38 @@ def test_backend_resolution_scopes():
     with set_backend(rd="auto"):
         assert resolve_rd_backend() == expected
     assert resolve_rd_backend() == expected  # no scope at all
+
+
+def test_engine_resolves_auto_rd_before_the_first_arrival():
+    """``auto`` RD imports jax to ask for the platform.  The engine makes
+    that call at construction, so the import lands before any arrival's
+    timed overhead; host-only policies and an explicit ``host`` scope
+    stay jax-free.  Runs in a fresh interpreter, where jax is not yet
+    loaded."""
+    script = textwrap.dedent(
+        """
+        import sys
+        from repro.backend import set_backend
+        from repro.runtime import SchedulingEngine, make_policy
+
+        SchedulingEngine(16, "wf")
+        with set_backend(rd="host"):
+            SchedulingEngine(16, "rd")
+        assert "jax" not in sys.modules, "host-only engines imported jax"
+        SchedulingEngine(16, make_policy("rd_plus", "ocwf"))
+        assert "jax" in sys.modules, "auto RD was not resolved at construction"
+        print("ok")
+        """
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", script],
+        capture_output=True,
+        text=True,
+        timeout=120,
+        env={**os.environ, "PYTHONPATH": _SRC, "JAX_PLATFORMS": "cpu"},  # reprolint: disable=R002 passthrough to a subprocess, no backend choice read
+    )
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert proc.stdout.strip() == "ok"
 
 
 def test_device_rejects_oversized_cluster():

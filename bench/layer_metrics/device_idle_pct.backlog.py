@@ -1,0 +1,8 @@
+"""Device: share of the traced window in which no op ran on the chip, in
+backlog cells."""
+
+
+def read(ctx):
+    if ctx.arrivals != "backlog" or not ctx.trace or ctx.trace["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - ctx.trace["busy_s"] / ctx.trace["window_s"])

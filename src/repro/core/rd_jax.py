@@ -64,8 +64,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.contracts import Interval, RangeClaim, choice, contract, span
-from repro.obs.session import device_profiler as _obs_device
+from repro.obs.session import active as _obs_active
+from repro.obs.session import span as _obs_span
 
+from .dispatch import phased_call
 from .instance import Assignment, AssignmentProblem, TaskGroup
 from .rd import RD_DEVICE_MAX_M, replica_deletion
 
@@ -327,8 +329,10 @@ def _rd_core(
     *,
     use_pallas: bool,
     interpret: bool,
-) -> _RDDev:
-    """Run the whole RD (deletion + dedup) for one instance on device."""
+) -> tuple[_RDDev, jax.Array]:
+    """Run the whole RD (deletion + dedup) for one instance on device;
+    returns the final state and the iterations the two loops ran (int32:
+    deletion iterations, strip or not, plus dedup strips)."""
     c_slots, a_max = holders0.shape
     m_servers = busy0.shape[0]
     busy0 = busy0.astype(jnp.int32)
@@ -370,11 +374,11 @@ def _rd_core(
     # still-valid sweep targets — exactly what the host's lazy re-ranking
     # heap realizes (stale keys are optimistic and validated at pop).
     def del_cond(carry):
-        st, targets0, best, done = carry
+        st, targets0, best, done, _ = carry
         return ~done & ~st.overflow
 
     def del_body(carry):
-        st, targets0, best, done = carry
+        st, targets0, best, done, iters = carry
         valid = targets0 & (st.busy_est == best) & (st.load > 0)
         new_sweep = ~valid.any()
         held = st.load > 0
@@ -408,41 +412,43 @@ def _rd_core(
             | (do_strip & (removed == 0))
             | (do_strip & (tmask & (st.multi == 0)).any())
         )
-        return st, targets0, best, done
+        return st, targets0, best, done, iters + 1
 
-    st, _, _, _ = jax.lax.while_loop(
+    st, _, _, _, iters = jax.lax.while_loop(
         del_cond,
         del_body,
         (st, jnp.zeros(m_servers, bool), jnp.asarray(-2, jnp.int32),
-         jnp.asarray(False)),
+         jnp.asarray(False), jnp.asarray(0, jnp.int32)),
     )
 
     # ---- final dedup phase ----------------------------------------------
     # One strip per iteration from the busiest multi-copy holder,
     # (busy_est, busy0, id) descending — the reference's lexsort pick.
-    def dd_cond(st):
+    def dd_cond(carry):
+        st, _ = carry
         return (st.multi > 0).any() & ~st.overflow
 
-    def dd_body(st):
+    def dd_body(carry):
+        st, iters = carry
         mask = st.multi > 0
         mask, _ = _refine_max(mask, st.busy_est)
         mask, _ = _refine_max(mask, busy0)
         m_servers_ = st.load.shape[0]
         m = m_servers_ - 1 - jnp.argmax(mask[::-1])  # ties -> largest id
         st, _ = strip(st, m)
-        return st
+        return st, iters + 1
 
-    return jax.lax.while_loop(dd_cond, dd_body, st)
+    return jax.lax.while_loop(dd_cond, dd_body, (st, iters))
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def _rd_device(busy0, mu, holders0, size0, cnt0, grp0, *, use_pallas,
                interpret):
-    st = _rd_core(
+    st, iters = _rd_core(
         busy0, mu, holders0, size0, cnt0, grp0,
         use_pallas=use_pallas, interpret=interpret,
     )
-    return st.size, st.cnt, st.grp, st.holders[:, 0], st.overflow
+    return st.size, st.cnt, st.grp, st.holders[:, 0], st.overflow, iters
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
@@ -453,13 +459,13 @@ def _rd_device_chain(busy0, mu, holders0, size0, cnt0, grp0, *,
     The RD twin of :func:`repro.core.wf_jax.water_fill_chain`: job ``i+1``
     sees ``b_m + ⌈load_m^i/μ_m^i⌉`` (eq. 2) exactly as if the burst were
     admitted one job at a time.  Padded jobs carry zero slots and commit
-    nothing.
+    nothing.  Outputs are per job, the loops' iteration count included.
     """
     m_servers = busy0.shape[0]
 
     def job_step(busy, inp):
         h0, s0, c0, g0, mu_j = inp
-        st = _rd_core(
+        st, iters = _rd_core(
             busy, mu_j, h0, s0, c0, g0,
             use_pallas=use_pallas, interpret=interpret,
         )
@@ -472,7 +478,7 @@ def _rd_device_chain(busy0, mu, holders0, size0, cnt0, grp0, *,
             loads > 0, _ceil_div(loads, mu_j.astype(jnp.int32)), 0
         )
         return busy_next, (st.size, st.cnt, st.grp, st.holders[:, 0],
-                           st.overflow)
+                           st.overflow, iters)
 
     _, outs = jax.lax.scan(
         job_step,
@@ -520,6 +526,14 @@ def _decode(
     result.phi = result.realized_phi(problem)
     result.validate(problem)
     return result
+
+
+def _observe_iters(iters) -> None:
+    """One ``rd.iters`` observation per job: the device loops' iterations."""
+    session = _obs_active()
+    if session is not None:
+        for n in np.atleast_1d(iters):
+            session.metrics.observe("rd.iters", int(n))
 
 
 def _resolve_device(backend: str, c_cap: int, a_pad: int) -> tuple[bool, bool]:
@@ -704,35 +718,26 @@ def replica_deletion_jax(
         max(2, max((len(g.servers) for g in problem.groups), default=1))
     )
     use_pallas, interpret = _resolve_device(backend, c_cap, a_pad)
-    downgrade = backend == "pallas" and not use_pallas
-    holders, size, cnt, grp = _dense_instance(problem, c_cap, a_pad)
-    prof = _obs_device()
-    t0 = prof.start() if prof is not None else 0.0
-    size_f, cnt_f, grp_f, srv_f, overflow = _rd_device(
-        jnp.asarray(problem.busy, jnp.int32),
-        jnp.asarray(problem.mu, jnp.int32),
-        jnp.asarray(holders),
-        jnp.asarray(size),
-        jnp.asarray(cnt),
-        jnp.asarray(grp),
-        use_pallas=use_pallas,
-        interpret=interpret,
+    size_f, cnt_f, grp_f, srv_f, overflow, iters = phased_call(
+        "rd",
+        "rd-device",
+        (problem.n_servers, c_cap, a_pad),  # the kernelcheck key
+        functools.partial(
+            _rd_device, use_pallas=use_pallas, interpret=interpret
+        ),
+        lambda: (
+            np.asarray(problem.busy, np.int32),
+            np.asarray(problem.mu, np.int32),
+            *_dense_instance(problem, c_cap, a_pad),
+        ),
+        downgrade=backend == "pallas" and not use_pallas,
+        fallback=lambda outs: bool(outs[4]),
     )
-    if bool(overflow):  # only under a capacity forced smaller — host re-run
-        if prof is not None:
-            prof.record(
-                "rd-device", (problem.n_servers, c_cap, a_pad), t0,
-                fallback=True, downgrade=downgrade,
-            )
+    if overflow:  # only under a capacity forced smaller — host re-run
         return replica_deletion(problem)
-    size_f, cnt_f = np.asarray(size_f), np.asarray(cnt_f)
-    grp_f, srv_f = np.asarray(grp_f), np.asarray(srv_f)
-    if prof is not None:  # past the host sync; sig = the kernelcheck key
-        prof.record(
-            "rd-device", (problem.n_servers, c_cap, a_pad), t0,
-            downgrade=downgrade,
-        )
-    return _decode(problem, size_f, cnt_f, grp_f, srv_f)
+    _observe_iters(iters)
+    with _obs_span("rd.decode"):
+        return _decode(problem, size_f, cnt_f, grp_f, srv_f)
 
 
 @contract(
@@ -806,57 +811,48 @@ def replica_deletion_jax_chain(
         )
     )
     use_pallas, interpret = _resolve_device(backend, c_cap, a_pad)
-    downgrade = backend == "pallas" and not use_pallas
     b_pad = _next_pow2(len(problems))
-    holders = np.full((b_pad, c_cap, a_pad), m, dtype=np.int32)
-    size = np.zeros((b_pad, c_cap), dtype=np.int32)
-    cnt = np.zeros((b_pad, c_cap), dtype=np.int32)
-    grp = np.zeros((b_pad, c_cap), dtype=np.int32)
-    mu = np.ones((b_pad, m), dtype=np.int32)
-    for i, p in enumerate(problems):
-        holders[i], size[i], cnt[i], grp[i] = _dense_instance(
-            p, c_cap, a_pad
-        )
-        mu[i] = p.mu
-    prof = _obs_device()
-    t0 = prof.start() if prof is not None else 0.0
-    size_f, cnt_f, grp_f, srv_f, overflow = _rd_device_chain(
-        jnp.asarray(base, jnp.int32),
-        jnp.asarray(mu),
-        jnp.asarray(holders),
-        jnp.asarray(size),
-        jnp.asarray(cnt),
-        jnp.asarray(grp),
-        use_pallas=use_pallas,
-        interpret=interpret,
+
+    def build():
+        holders = np.full((b_pad, c_cap, a_pad), m, dtype=np.int32)
+        size = np.zeros((b_pad, c_cap), dtype=np.int32)
+        cnt = np.zeros((b_pad, c_cap), dtype=np.int32)
+        grp = np.zeros((b_pad, c_cap), dtype=np.int32)
+        mu = np.ones((b_pad, m), dtype=np.int32)
+        for i, p in enumerate(problems):
+            holders[i], size[i], cnt[i], grp[i] = _dense_instance(
+                p, c_cap, a_pad
+            )
+            mu[i] = p.mu
+        return np.asarray(base, np.int32), mu, holders, size, cnt, grp
+
+    size_f, cnt_f, grp_f, srv_f, overflow, iters = phased_call(
+        "rd",
+        "rd-chain",
+        (m, c_cap, a_pad, b_pad),  # the kernelcheck key
+        functools.partial(
+            _rd_device_chain, use_pallas=use_pallas, interpret=interpret
+        ),
+        build,
+        downgrade=backend == "pallas" and not use_pallas,
+        fallback=lambda outs: bool(outs[4].any()),
     )
-    if bool(np.asarray(overflow).any()):
+    if overflow.any():
         # an overflowed job corrupts every later job's busy carry: discard
         # the device results and walk the burst on the host (identical
         # assignments — that is the parity guarantee)
         from .rd import host_commit_walk
 
-        if prof is not None:
-            prof.record(
-                "rd-chain", (m, c_cap, a_pad, b_pad), t0,
-                fallback=True, downgrade=downgrade,
-            )
         return host_commit_walk(problems)
     from .reorder import commit_busy
 
-    size_f = np.asarray(size_f)
-    cnt_f = np.asarray(cnt_f)
-    grp_f = np.asarray(grp_f)
-    srv_f = np.asarray(srv_f)
-    if prof is not None:  # past the host sync; sig = the kernelcheck key
-        prof.record(
-            "rd-chain", (m, c_cap, a_pad, b_pad), t0, downgrade=downgrade
-        )
-    busy = np.asarray(base)
-    out: list[Assignment] = []
-    for i, p in enumerate(problems):
-        prob_i = p if i == 0 else dataclasses.replace(p, busy=busy)
-        a = _decode(prob_i, size_f[i], cnt_f[i], grp_f[i], srv_f[i])
-        out.append(a)
-        busy = commit_busy(busy, a, prob_i.mu, m)
+    _observe_iters(iters[: len(problems)])
+    with _obs_span("rd.decode"):
+        busy = np.asarray(base)
+        out: list[Assignment] = []
+        for i, p in enumerate(problems):
+            prob_i = p if i == 0 else dataclasses.replace(p, busy=busy)
+            a = _decode(prob_i, size_f[i], cnt_f[i], grp_f[i], srv_f[i])
+            out.append(a)
+            busy = commit_busy(busy, a, prob_i.mu, m)
     return out

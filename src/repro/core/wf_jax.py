@@ -29,8 +29,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.contracts import choice, contract, span
-from repro.obs.session import device_profiler as _obs_device
+from repro.obs.session import span as _obs_span
 
+from .dispatch import phased_call
 from .instance import Assignment, AssignmentProblem
 
 __all__ = [
@@ -503,24 +504,19 @@ def water_filling_jax(
     if not problem.groups:
         return Assignment(alloc=[], phi=0)  # parity with host water_filling
     k_pad = _pad_k(len(problem.groups))
-    busy, mu, masks, demands = _dense_inputs([problem], k_pad)
     # resolve before the jit boundary so the cache keys on the
     # concrete backend (set_backend scopes stay effective per call)
     up, downgrade = _resolve_pallas(use_pallas, problem.n_servers)
-    prof = _obs_device()
-    t0 = prof.start() if prof is not None else 0.0
-    alloc, _, phi = _wf_groups_jit(
-        jnp.asarray(busy[0]), jnp.asarray(mu[0]),
-        jnp.asarray(masks[0]), jnp.asarray(demands[0]),
-        use_pallas=up,
+    alloc, phi = phased_call(
+        "wf",
+        "wf-groups",
+        (problem.n_servers, k_pad, up),  # the kernelcheck key
+        lambda *a: _wf_groups_jit(*a, use_pallas=up)[::2],
+        lambda: [x[0] for x in _dense_inputs([problem], k_pad)],
+        downgrade=downgrade,
     )
-    alloc, phi = np.asarray(alloc), int(phi)
-    if prof is not None:  # past the host sync; sig = the kernelcheck key
-        prof.record(
-            "wf-groups", (problem.n_servers, k_pad, up), t0,
-            downgrade=downgrade,
-        )
-    return _to_assignment(problem, alloc, phi)
+    with _obs_span("wf.decode"):
+        return _to_assignment(problem, alloc, int(phi))
 
 
 @contract(
@@ -565,26 +561,22 @@ def water_filling_jax_batch(
     if any(p.n_servers != m for p in problems):
         raise ValueError("batched WF requires a single cluster size")
     k_pad = _pad_k(max(len(p.groups) for p in problems))
-    busy, mu, masks, demands = _dense_inputs(problems, k_pad)
     # resolve before the jit boundary so the cache keys on the
     # concrete backend (set_backend scopes stay effective per call)
     up, downgrade = _resolve_pallas(use_pallas, m)
-    prof = _obs_device()
-    t0 = prof.start() if prof is not None else 0.0
-    alloc, _, phi = _wf_batch_jit(
-        jnp.asarray(busy), jnp.asarray(mu), jnp.asarray(masks),
-        jnp.asarray(demands), use_pallas=up,
+    alloc, phi = phased_call(
+        "wf",
+        "wf-batch",
+        (m, k_pad, up, len(problems)),  # the kernelcheck key
+        lambda *a: _wf_batch_jit(*a, use_pallas=up)[::2],
+        lambda: _dense_inputs(problems, k_pad),
+        downgrade=downgrade,
     )
-    alloc = np.asarray(alloc)
-    phi = np.asarray(phi)
-    if prof is not None:  # past the host sync; sig = the kernelcheck key
-        prof.record(
-            "wf-batch", (m, k_pad, up, len(problems)), t0,
-            downgrade=downgrade,
-        )
-    return [
-        _to_assignment(p, alloc[i], int(phi[i])) for i, p in enumerate(problems)
-    ]
+    with _obs_span("wf.decode"):
+        return [
+            _to_assignment(p, alloc[i], int(phi[i]))
+            for i, p in enumerate(problems)
+        ]
 
 
 @contract(
@@ -638,27 +630,30 @@ def water_filling_jax_chain(
             "busy vector (eq. 2 is committed inside the scan)"
         )
     k_pad = _pad_k(max(len(p.groups) for p in problems))
-    busy, mu, masks, demands = _dense_inputs(problems, k_pad)
     b_pad = _pad_k(len(problems))  # pad jobs too: O(log B) recompiles
-    if b_pad > len(problems):
-        pad = b_pad - len(problems)
-        mu = np.concatenate([mu, np.ones((pad, m), np.int32)])
-        masks = np.concatenate([masks, np.zeros((pad, k_pad, m), bool)])
-        demands = np.concatenate([demands, np.zeros((pad, k_pad), np.int32)])
+
+    def build():
+        busy, mu, masks, demands = _dense_inputs(problems, k_pad)
+        if b_pad > len(problems):
+            pad = b_pad - len(problems)
+            mu = np.concatenate([mu, np.ones((pad, m), np.int32)])
+            masks = np.concatenate([masks, np.zeros((pad, k_pad, m), bool)])
+            demands = np.concatenate(
+                [demands, np.zeros((pad, k_pad), np.int32)]
+            )
+        return busy[0], mu, masks, demands
+
     up, downgrade = _resolve_pallas(use_pallas, m)
-    prof = _obs_device()
-    t0 = prof.start() if prof is not None else 0.0
-    alloc, phi, _ = _wf_chain_jit(
-        jnp.asarray(busy[0]), jnp.asarray(mu), jnp.asarray(masks),
-        jnp.asarray(demands), use_pallas=up,
+    alloc, phi = phased_call(
+        "wf",
+        "wf-chain",
+        (m, k_pad, up, b_pad),  # the kernelcheck key
+        lambda *a: _wf_chain_jit(*a, use_pallas=up)[:2],
+        build,
+        downgrade=downgrade,
     )
-    alloc = np.asarray(alloc)
-    phi = np.asarray(phi)
-    if prof is not None:  # past the host sync; sig = the kernelcheck key
-        prof.record(
-            "wf-chain", (m, k_pad, up, b_pad), t0,
-            downgrade=downgrade,
-        )
-    return [
-        _to_assignment(p, alloc[i], int(phi[i])) for i, p in enumerate(problems)
-    ]
+    with _obs_span("wf.decode"):
+        return [
+            _to_assignment(p, alloc[i], int(phi[i]))
+            for i, p in enumerate(problems)
+        ]

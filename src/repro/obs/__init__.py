@@ -11,10 +11,17 @@ Three surfaces, one session object:
   power-of-two histograms (queue depths, eq. 2 busy levels, locality
   tiers, steal/spec win-loss accounting, serve latency), snapshotted
   per tick at a configurable cadence.
-- **device profiling** (:class:`repro.obs.session.DeviceProfiler`) —
-  compile-vs-execute wall time and jit-cache hit/miss around the
-  ``wf_jax``/``rd_jax`` adapters, keyed by the kernelcheck signatures,
-  plus host-fallback counts.
+- **host spans** (:func:`span`, :meth:`ObsSession.span`) — one helper
+  for every host timing (tick phases ``tick.<phase>``, ``sched.admit``,
+  the dispatch phases ``rd.*``/``wf.*``): each span lands in histogram
+  ``<name>.us``, in the ring buffer when tracing, and, through
+  ``jax.profiler.TraceAnnotation`` once jax is loaded, on the host line
+  of a profiler trace.
+- **device profiling** (:func:`device_span`,
+  :class:`repro.obs.session.DeviceProfiler`) — the wall time of every
+  ``wf_jax``/``rd_jax`` dispatch (``device.<kind>.exec_us``), keyed by
+  the kernelcheck signatures, plus host-fallback and Pallas-downgrade
+  counts.
 
 Everything hangs off :class:`ObsSession`, activated ambiently::
 
@@ -40,11 +47,15 @@ from __future__ import annotations
 from . import clock
 from .metrics import Histogram, Metrics
 from .session import (
+    NO_SPAN,
     DeviceProfiler,
+    DeviceSpan,
     ObsSession,
+    Span,
     active,
-    device_profiler,
+    device_span,
     observe,
+    span,
 )
 from .trace import KIND_NAMES, SLOT_US, TraceRecorder, parse_chrome_trace
 
@@ -53,10 +64,14 @@ __all__ = [
     "Histogram",
     "Metrics",
     "DeviceProfiler",
+    "DeviceSpan",
+    "NO_SPAN",
     "ObsSession",
+    "Span",
     "active",
-    "device_profiler",
+    "device_span",
     "observe",
+    "span",
     "KIND_NAMES",
     "SLOT_US",
     "TraceRecorder",

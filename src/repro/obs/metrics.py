@@ -16,14 +16,15 @@ Naming convention (``.``-separated, catalogued in
 ``docs/OBSERVABILITY.md``): ``jobs.*`` lifecycle counts, ``queue.*``
 depths, ``busy.*`` eq. 2 levels, ``locality.*`` hit tiers, ``steal.*`` /
 ``spec.*`` outcome accounting, ``placement.*`` churn, ``serve.*``
-latency, ``device.<kind>.*`` dispatch profiling.
+latency, ``device.<kind>.*`` dispatch profiling, ``<span>.us`` host
+spans, ``rd.iters`` the RD device loop's iterations per job.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-__all__ = ["Histogram", "Metrics", "perf_regressions"]
+__all__ = ["Histogram", "Metrics"]
 
 _NBUCKETS = 64
 
@@ -160,52 +161,3 @@ class Metrics:
     def save_npz(self, path: str) -> None:
         np.savez_compressed(path, **self.to_table())
 
-
-def _final(table, key: str) -> float:
-    arr = np.asarray(table[key]).ravel()
-    return float(arr[-1]) if arr.size else 0.0
-
-
-def perf_regressions(
-    old,
-    new,
-    *,
-    threshold: float = 2.0,
-    min_value: float = 0.0,
-) -> list[dict]:
-    """Compare two metric tables (:meth:`Metrics.to_table` dicts or
-    loaded ``.npz`` mappings) on the performance-tracking columns:
-    control-plane tick-phase host times (``hist.tick.<phase>.us`` mean
-    and p99) and cumulative device compile counts
-    (``counter.device.<kind>.compiles``, final row).
-
-    Returns one ``{"name", "old", "new", "ratio"}`` record per column
-    where ``new > threshold * old`` — including columns absent from the
-    old run (``old == 0``, reported with an infinite ratio).  Columns
-    whose new value is at or below ``min_value`` are skipped, which is
-    the noise floor for sub-microsecond host-time jitter."""
-    keys = set(old) & set(new)
-    watched = [
-        k
-        for k in sorted(keys)
-        if (
-            k.startswith("hist.tick.")
-            and (k.endswith(".mean") or k.endswith(".p99"))
-        )
-        or (k.startswith("counter.device.") and k.endswith(".compiles"))
-    ]
-    out: list[dict] = []
-    for k in watched:
-        o, n = _final(old, k), _final(new, k)
-        if n <= min_value:
-            continue
-        if n > threshold * o:
-            out.append(
-                {
-                    "name": k,
-                    "old": o,
-                    "new": n,
-                    "ratio": (n / o) if o else float("inf"),
-                }
-            )
-    return out

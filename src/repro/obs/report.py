@@ -15,13 +15,6 @@ and the device-dispatch profile.
 Defaults mirror the acceptance scenario: ``bursty`` with stealing and
 speculation on, so the emitted trace contains job-lifecycle spans with
 steal/spec causality links out of the box.
-
-``--diff OLD.npz NEW.npz`` compares two metrics artifacts instead of
-running: control-plane tick-phase host times and device compile counts
-are checked column-by-column (:func:`repro.obs.metrics.
-perf_regressions`), and the exit status is non-zero when any column
-regressed by more than ``--threshold``× — nightly CI diffs each run's
-``OBS_*.metrics.npz`` against the previous one with exactly this mode.
 """
 
 from __future__ import annotations
@@ -45,29 +38,6 @@ def _section(title: str) -> str:
     return f"\n{title}\n{'-' * len(title)}"
 
 
-def _diff(args) -> int:
-    import numpy as np
-
-    from repro.obs.metrics import perf_regressions
-
-    old_path, new_path = args.diff
-    with np.load(old_path) as old, np.load(new_path) as new:
-        regs = perf_regressions(
-            old, new, threshold=args.threshold, min_value=args.min_value
-        )
-    if not regs:
-        print(
-            f"# no perf regression over {args.threshold}x "
-            f"({old_path} -> {new_path})"
-        )
-        return 0
-    print(f"# {len(regs)} perf regression(s) over {args.threshold}x:")
-    for r in regs:
-        ratio = "inf" if r["ratio"] == float("inf") else f"{r['ratio']:.2f}"
-        print(f"  {r['name']}: {r['old']:.1f} -> {r['new']:.1f} ({ratio}x)")
-    return 1
-
-
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m repro.obs.report", description=__doc__
@@ -87,26 +57,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--metrics-every", type=int, default=1)
     ap.add_argument("--capacity", type=int, default=1 << 18)
     ap.add_argument("--out", default="results")
-    ap.add_argument(
-        "--diff",
-        nargs=2,
-        metavar=("OLD", "NEW"),
-        help="compare two metrics .npz artifacts instead of running; "
-        "exit 1 when a tick-phase time or device compile count regressed "
-        "by more than --threshold x",
-    )
-    ap.add_argument("--threshold", type=float, default=2.0)
-    ap.add_argument(
-        "--min-value",
-        type=float,
-        default=0.0,
-        help="ignore diff columns whose new value is at or below this "
-        "(noise floor for sub-microsecond host times)",
-    )
     args = ap.parse_args(argv)
-
-    if args.diff:
-        return _diff(args)
 
     # runtime imports are deferred so `--help` never pays the jax import
     import repro.traces  # noqa: F401  (registers the scenario registry)

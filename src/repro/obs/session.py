@@ -11,10 +11,15 @@ one of two ways:
   construction;
 - **module-level layers** (the ``wf_jax``/``rd_jax`` adapters,
   :class:`repro.placement.store.PlacementStore`, the serve engines) read
-  :func:`active` / :func:`device_profiler` per call.
+  :func:`active` / :func:`span` / :func:`device_span` per call.
 
 Either way a disabled run pays one attribute/None check per site and
-nothing else.  Activate with::
+nothing else: a span site then gets the shared no-op :data:`NO_SPAN`.
+Host time is timed in :class:`Span` objects, one helper for every
+layer: a span lands in a histogram, in the ring buffer, and (through
+``jax.profiler.TraceAnnotation``, once jax is loaded) on the host line
+of a profiler trace, the line the device's idle gaps are put down to.
+Activate with::
 
     from repro import obs
 
@@ -24,8 +29,8 @@ nothing else.  Activate with::
     session.metrics.to_table()
 
 **Schedule invariance is the contract**: every hook is observation-only.
-No hook mutates cluster or queue state, calls into jax, draws random
-numbers, or feeds a wall-clock reading back into a decision — so a run
+No hook mutates cluster or queue state, calls into jax (a span's
+profiler annotation only records), draws random numbers, or feeds a wall-clock reading back into a decision — so a run
 with a session active is schedule-identical (bit-identical ``SimResult``)
 to one without, which ``tests/test_obs.py`` proves across scenarios ×
 orderings under ``--sanitize``.
@@ -34,6 +39,7 @@ orderings under ``--sanitize``.
 from __future__ import annotations
 
 import contextlib
+import sys
 from typing import Iterator
 
 from . import clock
@@ -50,12 +56,40 @@ from .trace import (
     INST_SPEC_RESOLVE,
     INST_STEAL,
     SPAN_JOB,
+    SPAN_HOST,
     SPAN_SERVE,
-    SPAN_TICK,
     TraceRecorder,
 )
 
-__all__ = ["ObsSession", "DeviceProfiler", "observe", "active", "device_profiler"]
+__all__ = [
+    "ObsSession",
+    "DeviceProfiler",
+    "DeviceSpan",
+    "NO_SPAN",
+    "Span",
+    "active",
+    "device_span",
+    "observe",
+    "span",
+]
+
+# the shared no-op every span site gets when observability is off
+NO_SPAN = contextlib.nullcontext()
+
+# default histogram of ObsSession.span: ``<name>.us``
+_NAME_US = "<name>.us"
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` once jax is loaded, else None:
+    this package never imports jax itself, so host-only runs stay
+    jax-free."""
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
 
 # spec-pair resolution codes (INST_SPEC_RESOLVE.b)
 SPEC_ORIGINAL_WON = 0
@@ -63,66 +97,105 @@ SPEC_CLONE_WON = 1
 SPEC_ABORTED = 2
 
 
-class DeviceProfiler:
-    """Wall-time + jit-cache accounting around device dispatches.
+class Span:
+    """One timed host span, on both clocks.
 
-    The cache-miss heuristic mirrors jax's jit cache: the first call for
-    a given kernelcheck signature (``("wf-groups", m, k_pad, up)``,
-    ``("rd-device", m, c_cap, a_pad)``, ...) traces and compiles, so its
-    wall time is attributed to ``compile_us``; subsequent calls with the
-    same signature hit the cache and land in ``exec_us``.  Host
-    fallbacks (RD capacity overflow) are counted separately — their wall
-    time is genuine scheduling cost, not device time — and so are Pallas
-    downgrades: a dispatch that resolved to the Pallas kernel but ran
-    the jnp pipeline because its geometry is past the kernel's
-    single-block bounds.
-    """
+    Entering opens a ``jax.profiler.TraceAnnotation`` of the same name
+    (when jax is already loaded), so the span lands on the host line of
+    a profiler trace; the wall time is read from :mod:`repro.obs.clock`
+    inside the annotation.  On exit the time goes into the histogram
+    ``hist`` (none when ``hist`` is None) and, with the session's trace
+    on, into the ring buffer as a :data:`SPAN_HOST` record."""
 
-    def __init__(self, session: "ObsSession"):
+    __slots__ = ("_session", "name", "_hist", "_ann", "_t0")
+
+    def __init__(self, session: "ObsSession", name: str, hist: str | None):
         self._session = session
-        self._seen: set[tuple] = set()
+        self.name = name
+        self._hist = hist
 
-    def start(self) -> float:
-        return clock.perf_counter()
+    def __enter__(self) -> "Span":
+        ann = _trace_annotation()
+        self._ann = ann(self.name) if ann is not None else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = clock.perf_counter()
+        return self
 
-    def record(
-        self,
-        kind: str,
-        sig: tuple,
-        t0: float,
-        *,
-        fallback: bool = False,
-        downgrade: bool = False,
-    ) -> None:
-        wall_us = clock.us_since(t0)
-        key = (kind, sig)
-        miss = key not in self._seen
-        if miss:
-            self._seen.add(key)
+    def __exit__(self, *exc) -> None:
+        wall_us = clock.us_since(self._t0)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._close(self._t0, wall_us)
+
+    def _close(self, t0: float, wall_us: int) -> None:
+        s = self._session
+        if self._hist is not None:
+            s.metrics.observe(self._hist, wall_us)
+        if s.trace is not None:
+            s.trace.record(
+                SPAN_HOST,
+                ts=s.host_us(t0),
+                dur=wall_us,
+                a=s.trace.intern(self.name),
+            )
+
+
+class DeviceSpan(Span):
+    """The span of one device dispatch, ``device.<kind>``: its wall time
+    lands in ``device.<kind>.exec_us`` and the ring buffer's
+    :data:`INST_DEVICE` record, keyed by the kernelcheck signature
+    (``("wf-groups", m, k_pad, up)``, ``("rd-device", m, c_cap, a_pad)``,
+    ...).  Set ``fallback`` before exit when the dispatch's result was
+    discarded for a host re-run (RD capacity overflow): its wall time is
+    genuine scheduling cost, not device time.  ``downgrade`` flags a
+    dispatch that asked for the Pallas kernel but ran the jnp pipeline
+    because its geometry is past the kernel's single-block bounds."""
+
+    __slots__ = ("kind", "sig", "fallback", "downgrade")
+
+    def __init__(
+        self, session: "ObsSession", kind: str, sig: tuple, downgrade: bool
+    ):
+        super().__init__(session, f"device.{kind}", f"device.{kind}.exec_us")
+        self.kind = kind
+        self.sig = sig
+        self.fallback = False
+        self.downgrade = downgrade
+
+    def _close(self, t0: float, wall_us: int) -> None:
         s = self._session
         m = s.metrics
-        m.inc(f"device.{kind}.calls")
-        if miss:
-            m.inc(f"device.{kind}.compiles")
-            m.observe(f"device.{kind}.compile_us", wall_us)
-        else:
-            m.observe(f"device.{kind}.exec_us", wall_us)
-        if fallback:
-            m.inc(f"device.{kind}.host_fallback")
-        if downgrade:
-            m.inc(f"device.{kind}.pallas_downgrade")
+        m.inc(f"device.{self.kind}.calls")
+        m.observe(self._hist, wall_us)
+        if self.fallback:
+            m.inc(f"device.{self.kind}.host_fallback")
+        if self.downgrade:
+            m.inc(f"device.{self.kind}.pallas_downgrade")
         trace = s.trace
         if trace is not None:
             trace.record(
                 INST_DEVICE,
                 ts=s.host_us(t0),
                 dur=wall_us,
-                a=trace.intern(f"{kind}{sig}"),
-                b=(1 if miss else 0)
-                | (2 if fallback else 0)
-                | (4 if downgrade else 0),
+                a=trace.intern(f"{self.kind}{self.sig}"),
+                b=(2 if self.fallback else 0) | (4 if self.downgrade else 0),
                 c=wall_us,
             )
+
+
+class DeviceProfiler:
+    """Dispatch accounting around the ``wf_jax``/``rd_jax`` adapters:
+    every call's host-observed wall time (upload, run, sync, readback)
+    lands in ``device.<kind>.exec_us``, with counts of calls, host
+    fallbacks and Pallas downgrades.  Compiles are not guessed here:
+    ``jax.monitoring`` counts the programs actually lowered."""
+
+    def __init__(self, session: "ObsSession"):
+        self._session = session
+
+    def span(self, kind: str, sig: tuple, *, downgrade: bool = False) -> DeviceSpan:
+        return DeviceSpan(self._session, kind, sig, downgrade)
 
 
 class ObsSession:
@@ -211,20 +284,16 @@ class ObsSession:
     def job_retry(self, t: int, job_id: int) -> None:
         self.metrics.inc("jobs.retried")
 
-    # ---- control-plane phases -------------------------------------------
+    # ---- host spans ------------------------------------------------------
 
-    def tick_phase(self, name: str, t0: float) -> None:
-        """Close a host-time phase span opened at ``t0`` (a
-        :meth:`DeviceProfiler.start`-style ``perf_counter`` reading)."""
-        wall_us = clock.us_since(t0)
-        self.metrics.observe(f"tick.{name}.us", wall_us)
-        if self.trace is not None:
-            self.trace.record(
-                SPAN_TICK,
-                ts=self.host_us(t0),
-                dur=wall_us,
-                a=self.trace.intern(name),
-            )
+    def span(self, name: str, hist: str | None = _NAME_US) -> Span:
+        """A :class:`Span` named ``name`` whose wall time lands in
+        histogram ``hist`` (default ``<name>.us``; None records none)::
+
+            with session.span("tick.service"):
+                ...
+        """
+        return Span(self, name, f"{name}.us" if hist is _NAME_US else hist)
 
     # ---- stealing / speculation / reassignment ---------------------------
 
@@ -362,10 +431,20 @@ def active() -> ObsSession | None:
     return _ACTIVE[-1] if _ACTIVE else None
 
 
-def device_profiler() -> DeviceProfiler | None:
-    """The active session's device profiler (None when off — the adapter
-    hot paths guard on this and skip all timing)."""
-    return _ACTIVE[-1].device if _ACTIVE else None
+def span(name: str, hist: str | None = _NAME_US):
+    """:meth:`ObsSession.span` on the active session, or the shared
+    :data:`NO_SPAN` when observability is off."""
+    return _ACTIVE[-1].span(name, hist) if _ACTIVE else NO_SPAN
+
+
+def device_span(kind: str, sig: tuple, *, downgrade: bool = False):
+    """:meth:`DeviceProfiler.span` on the active session, or the shared
+    :data:`NO_SPAN` (which enters as None) when observability or device
+    profiling is off."""
+    prof = _ACTIVE[-1].device if _ACTIVE else None
+    if prof is None:
+        return NO_SPAN
+    return prof.span(kind, sig, downgrade=downgrade)
 
 
 @contextlib.contextmanager

@@ -4,7 +4,7 @@
 columns — ``(kind, ts, dur, a, b, c, link)`` — in preallocated numpy
 arrays, overwriting the oldest rows once ``capacity`` is exceeded
 (:attr:`TraceRecorder.dropped` counts the overwritten rows).  Strings
-(placement blocks, device-dispatch signatures, tick-phase names) are
+(placement blocks, device-dispatch signatures, span names) are
 interned to small integers so the hot recording path never formats or
 hashes anything larger than a tuple.
 
@@ -15,7 +15,7 @@ Two exports:
 - :meth:`TraceRecorder.to_chrome_trace` — Chrome/Perfetto
   ``trace_event`` JSON (open at https://ui.perfetto.dev).  Sim-time
   events render at :data:`SLOT_US` microseconds per scheduler slot;
-  host-time events (tick phases, device dispatches) use real
+  host-time events (host spans, device dispatches) use real
   microseconds since the session started.  Steal/speculation causality
   is emitted as flow-event pairs (``ph: "s"``/``"f"``) binding the job's
   lifecycle span to the slice on the server that picked the work up.
@@ -40,15 +40,16 @@ INST_SPEC_LAUNCH    sim   slot / -                 job / src / dst / flow
 INST_SPEC_RESOLVE   sim   slot / -                 job / winner / tasks / flow
 INST_PLACEMENT      sim   slot / -                 str / server / - / -
 SPAN_SERVE          sim   submit slot / latency    rid / - / tokens / -
-SPAN_TICK           host  start us / wall us       str(phase) / - / - / -
+SPAN_HOST           host  start us / wall us       str(name) / - / - / -
 INST_DEVICE         host  start us / wall us       str(sig) / flags / ns / -
 ==================  ====  =======================  ==========================
 
 ``INST_SPEC_RESOLVE.b``: 0 = original copy won, 1 = clone won, 2 = pair
-aborted before completion.  ``INST_DEVICE.b``: bit 0 = jit-cache miss
-(compile included in the wall time), bit 1 = host fallback taken,
-bit 2 = Pallas requested but the jnp pipeline ran (geometry past the
-kernel's bounds).
+aborted before completion.  ``INST_DEVICE.b``: bit 1 = host fallback
+taken, bit 2 = Pallas requested but the jnp pipeline ran (geometry past
+the kernel's bounds); bit 0 is unused.  ``SPAN_HOST`` is one
+:class:`repro.obs.session.Span` (a tick phase ``tick.<phase>``,
+``sched.admit``, a dispatch phase ``rd.*``/``wf.*``).
 """
 
 from __future__ import annotations
@@ -76,7 +77,7 @@ INST_SPEC_LAUNCH = 8
 INST_SPEC_RESOLVE = 9
 INST_PLACEMENT = 10
 SPAN_SERVE = 11
-SPAN_TICK = 12
+SPAN_HOST = 12
 INST_DEVICE = 13
 
 KIND_NAMES: dict[int, str] = {
@@ -91,7 +92,7 @@ KIND_NAMES: dict[int, str] = {
     INST_SPEC_RESOLVE: "spec-resolve",
     INST_PLACEMENT: "placement",
     SPAN_SERVE: "serve",
-    SPAN_TICK: "tick",
+    SPAN_HOST: "span",
     INST_DEVICE: "device",
 }
 
@@ -102,7 +103,7 @@ _PID_HOST = 2
 _PID_SERVE = 3
 _PID_DEVICE = 4
 
-_HOST_TIME_KINDS = frozenset((SPAN_TICK, INST_DEVICE))
+_HOST_TIME_KINDS = frozenset((SPAN_HOST, INST_DEVICE))
 
 _FIELDS = ("kind", "ts", "dur", "a", "b", "c", "link")
 
@@ -212,7 +213,7 @@ class TraceRecorder:
         for pid, name in (
             (_PID_JOBS, "jobs (1 slot = 1 ms)"),
             (_PID_SERVERS, "servers (1 slot = 1 ms)"),
-            (_PID_HOST, "control plane (host time)"),
+            (_PID_HOST, "host spans (host time)"),
             (_PID_SERVE, "serve requests (1 slot = 1 ms)"),
             (_PID_DEVICE, "device dispatch (host time)"),
         ):
@@ -272,13 +273,13 @@ class TraceRecorder:
                         "args": args,
                     }
                 )
-            elif kind == SPAN_TICK:
+            elif kind == SPAN_HOST:
                 thread_name(_PID_HOST, a, self._name(a))
                 events.append(
                     {
                         "ph": "X",
                         "name": self._name(a),
-                        "cat": "tick",
+                        "cat": "span",
                         "pid": _PID_HOST,
                         "tid": a,
                         "ts": ts,
@@ -299,7 +300,6 @@ class TraceRecorder:
                         "dur": max(dur, 1),
                         "args": dict(
                             args,
-                            cache_miss=bool(b & 1),
                             host_fallback=bool(b & 2),
                             pallas_downgrade=bool(b & 4),
                         ),

@@ -53,7 +53,7 @@ from repro.core import (
     resolve_rd_backend,
 )
 from repro.obs import clock
-from repro.obs.session import ObsSession, active as obs_active
+from repro.obs.session import NO_SPAN, ObsSession, active as obs_active
 from repro.placement import PlacedJob, PlacementEvent, PlacementStore
 
 from .cluster import ClusterState
@@ -451,6 +451,14 @@ class SchedulingEngine:
             store.record_access(block, grp.size)
         return resolved
 
+    def _admit_span(self):
+        """The host span ``sched.admit`` around one admission's timed
+        region; its time stays in ``sched.overhead_us``, per job, through
+        :meth:`ObsSession.job_admitted`."""
+        if self.obs is None:
+            return NO_SPAN
+        return self.obs.span("sched.admit", hist=None)
+
     def _admit_one(self, job: Job) -> float | None:
         """Place one arriving job; returns scheduling wall time (None if
         the job's data is already unavailable)."""
@@ -465,25 +473,26 @@ class SchedulingEngine:
             cluster.mark_failed(job.job_id)
             return None
         groups, gids = proj
-        t0 = clock.perf_counter()
-        if self.policy.reorders:
-            self._reschedule(
-                [(
-                    OutstandingJob(
-                        job_id=job.job_id,
-                        groups=groups,
-                        mu=cluster.effective_mu(job),
-                    ),
-                    gids,
-                )]
-            )
-        else:
-            prob = cluster.problem_for(job, groups)
-            assignment = self.policy.assign(prob)
-            if self.debug:
-                assignment.validate(prob)
-            cluster.enqueue(job.job_id, assignment, gids)
-        elapsed = clock.perf_counter() - t0
+        with self._admit_span():
+            t0 = clock.perf_counter()
+            if self.policy.reorders:
+                self._reschedule(
+                    [(
+                        OutstandingJob(
+                            job_id=job.job_id,
+                            groups=groups,
+                            mu=cluster.effective_mu(job),
+                        ),
+                        gids,
+                    )]
+                )
+            else:
+                prob = cluster.problem_for(job, groups)
+                assignment = self.policy.assign(prob)
+                if self.debug:
+                    assignment.validate(prob)
+                cluster.enqueue(job.job_id, assignment, gids)
+            elapsed = clock.perf_counter() - t0
         if self.obs is not None:
             self.obs.job_admitted(self.obs.sim_now, job.job_id, elapsed)
         return elapsed
@@ -535,25 +544,26 @@ class SchedulingEngine:
             return self._admit_burst_reorder(batch)
         if batch_fn is None:
             return [o for j in batch if (o := self._admit_one(j)) is not None]
-        t0 = clock.perf_counter()
-        admitted = self._project_batch(batch)
-        if not admitted:
-            return []
-        base_busy = cluster.busy_times()
-        problems = [
-            AssignmentProblem(
-                busy=base_busy, mu=cluster.effective_mu(job), groups=groups
-            )
-            for job, groups, _ in admitted
-        ]
-        assignments = batch_fn(problems)
-        for (job, _, gids), prob, assignment in zip(
-            admitted, problems, assignments
-        ):
-            if self.debug:
-                assignment.validate(prob)
-            cluster.enqueue(job.job_id, assignment, gids)
-        elapsed = clock.perf_counter() - t0
+        with self._admit_span():
+            t0 = clock.perf_counter()
+            admitted = self._project_batch(batch)
+            if not admitted:
+                return []
+            base_busy = cluster.busy_times()
+            problems = [
+                AssignmentProblem(
+                    busy=base_busy, mu=cluster.effective_mu(job), groups=groups
+                )
+                for job, groups, _ in admitted
+            ]
+            assignments = batch_fn(problems)
+            for (job, _, gids), prob, assignment in zip(
+                admitted, problems, assignments
+            ):
+                if self.debug:
+                    assignment.validate(prob)
+                cluster.enqueue(job.job_id, assignment, gids)
+            elapsed = clock.perf_counter() - t0
         if self.obs is not None:
             for job, _, _ in admitted:
                 self.obs.job_admitted(
@@ -572,22 +582,23 @@ class SchedulingEngine:
         schedule-identical at 1/len(batch) of the rescan cost.
         """
         cluster = self.cluster
-        t0 = clock.perf_counter()
-        extras = [
-            (
-                OutstandingJob(
-                    job_id=job.job_id,
-                    groups=groups,
-                    mu=cluster.effective_mu(job),
-                ),
-                gids,
-            )
-            for job, groups, gids in self._project_batch(batch)
-        ]
-        if not extras:
-            return []
-        self._reschedule(extras)
-        elapsed = clock.perf_counter() - t0
+        with self._admit_span():
+            t0 = clock.perf_counter()
+            extras = [
+                (
+                    OutstandingJob(
+                        job_id=job.job_id,
+                        groups=groups,
+                        mu=cluster.effective_mu(job),
+                    ),
+                    gids,
+                )
+                for job, groups, gids in self._project_batch(batch)
+            ]
+            if not extras:
+                return []
+            self._reschedule(extras)
+            elapsed = clock.perf_counter() - t0
         if self.obs is not None:
             for extra, _ in extras:
                 self.obs.job_admitted(

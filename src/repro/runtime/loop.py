@@ -73,8 +73,8 @@ from typing import Callable
 from repro import registry
 from repro.analysis import runtime as sanitizers
 from repro.core import Job
-from repro.obs import clock
 from repro.obs.session import (
+    NO_SPAN,
     SPEC_ABORTED,
     ObsSession,
     active as obs_active,
@@ -96,8 +96,14 @@ _P_REQUEST = 2  # serve-request routing
 _P_SERVICE = 3  # one ClusterState.process_slot
 _P_HEARTBEAT = 4  # router / serve-pool drain
 
-# tick-phase names for obs spans, indexed by priority
-_PHASE_NAMES = ("event", "arrival", "request", "service", "heartbeat")
+# tick-phase span names (histograms ``<name>.us``), indexed by priority
+_PHASE_NAMES = (
+    "tick.event",
+    "tick.arrival",
+    "tick.request",
+    "tick.service",
+    "tick.heartbeat",
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -369,24 +375,22 @@ class ControlPlane:
         o = self.obs
         if o is not None:
             o.sim_now = t
-            t0 = clock.perf_counter()
-        if prio == _P_EVENT:
-            self._handle_cluster_event(t, payload)
-        elif prio == _P_ARRIVAL:
-            batch = [payload]
-            while self._heap and self._heap[0][:2] == (t, _P_ARRIVAL):
-                batch.append(heapq.heappop(self._heap)[3])
-            self._handle_arrivals(t, batch)
-        elif prio == _P_REQUEST:
-            self._handle_request(t, payload)
-        elif prio == _P_SERVICE:
-            self._service_at = None
-            self._handle_service(t)
-        else:
-            self._heartbeat_pending = False
-            self._handle_heartbeat(t)
-        if o is not None:
-            o.tick_phase(_PHASE_NAMES[prio], t0)
+        with o.span(_PHASE_NAMES[prio]) if o is not None else NO_SPAN:
+            if prio == _P_EVENT:
+                self._handle_cluster_event(t, payload)
+            elif prio == _P_ARRIVAL:
+                batch = [payload]
+                while self._heap and self._heap[0][:2] == (t, _P_ARRIVAL):
+                    batch.append(heapq.heappop(self._heap)[3])
+                self._handle_arrivals(t, batch)
+            elif prio == _P_REQUEST:
+                self._handle_request(t, payload)
+            elif prio == _P_SERVICE:
+                self._service_at = None
+                self._handle_service(t)
+            else:
+                self._heartbeat_pending = False
+                self._handle_heartbeat(t)
 
     def _ensure_service(self, t: int) -> None:
         if self._service_at is None:

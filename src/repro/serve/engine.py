@@ -20,7 +20,7 @@ from repro.analysis import runtime as sanitizers
 from repro.core import AssignmentProblem, TaskGroup
 from repro.models import ModelConfig, decode_step, init_decode_cache, prefill
 from repro.obs.session import active as _obs_active
-from repro.obs.session import device_profiler as _obs_device
+from repro.obs.session import device_span as _obs_device_span
 from repro.runtime.policies import AssignFn, get_assigner
 
 __all__ = [
@@ -109,17 +109,15 @@ class ServeEngine:
         masked out of their caches by per-slot positions)."""
         tokens = np.zeros((len(self.slots), 1), np.int32)
         tokens[slot, 0] = token
-        prof = _obs_device()
-        t0 = prof.start() if prof is not None else 0.0
-        logits, cache = self._decode(
-            self.params, jnp.asarray(tokens), self._with_pos()
-        )
-        # only commit slot's position advance
-        self._pos[slot] += 1
-        self.cache = cache
-        nxt = int(np.asarray(logits[slot, 0]).argmax())
-        if prof is not None:  # past the host sync: honest dispatch wall time
-            prof.record("serve-decode", (len(self.slots),), t0)
+        # the span closes past the host sync: honest dispatch wall time
+        with _obs_device_span("serve-decode", (len(self.slots),)):
+            logits, cache = self._decode(
+                self.params, jnp.asarray(tokens), self._with_pos()
+            )
+            # only commit slot's position advance
+            self._pos[slot] += 1
+            self.cache = cache
+            nxt = int(np.asarray(logits[slot, 0]).argmax())
         if self._guard is not None:  # sync point: dispatch completed above
             self._guard.verify()
         return nxt
@@ -145,15 +143,13 @@ class ServeEngine:
         tokens = np.zeros((len(self.slots), 1), np.int32)
         for i in active:
             tokens[i, 0] = self.slots[i]._last
-        prof = _obs_device()
-        t0 = prof.start() if prof is not None else 0.0
-        logits, cache = self._decode(
-            self.params, jnp.asarray(tokens), self._with_pos()
-        )
-        self.cache = cache
-        nxt = np.asarray(logits[:, 0].argmax(axis=-1))
-        if prof is not None:  # past the host sync: honest dispatch wall time
-            prof.record("serve-decode", (len(self.slots),), t0)
+        # the span closes past the host sync: honest dispatch wall time
+        with _obs_device_span("serve-decode", (len(self.slots),)):
+            logits, cache = self._decode(
+                self.params, jnp.asarray(tokens), self._with_pos()
+            )
+            self.cache = cache
+            nxt = np.asarray(logits[:, 0].argmax(axis=-1))
         if self._guard is not None:  # sync point: dispatch completed above
             self._guard.verify()
         finished = []

@@ -12,7 +12,12 @@ The Chrome-export half pins the acceptance artifact: a valid
 and a steal/speculation causality flow pair.
 """
 
+import dataclasses
 import json
+import os
+import subprocess
+import sys
+import textwrap
 
 import numpy as np
 import pytest
@@ -30,6 +35,8 @@ from repro.obs.session import (
 )
 from repro.runtime import ControlPlane, SchedulingEngine, make_policy
 from repro.traces import generate
+
+_SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
 
 # ---- ring buffer ------------------------------------------------------------
 
@@ -89,9 +96,9 @@ def _synthetic_recorder() -> TraceRecorder:
     rec.record(
         trace_mod.INST_PLACEMENT, ts=4, a=rec.intern("evict:blk0"), b=3
     )
-    rec.record(trace_mod.SPAN_TICK, ts=100, dur=50, a=rec.intern("service"))
+    rec.record(trace_mod.SPAN_HOST, ts=100, dur=50, a=rec.intern("tick.service"))
     rec.record(
-        trace_mod.INST_DEVICE, ts=200, dur=30, a=rec.intern("wf-groups"), b=1, c=30
+        trace_mod.INST_DEVICE, ts=200, dur=30, a=rec.intern("wf-groups"), b=2, c=30
     )
     return rec
 
@@ -128,8 +135,9 @@ def test_chrome_trace_shape_is_valid():
         assert starts[0]["id"] == ends[0]["id"]
     # the device dispatch decodes its flag bits
     device = [e for e in events if e.get("cat") == "device"]
-    assert device[0]["args"]["cache_miss"] is True
-    assert device[0]["args"]["host_fallback"] is False
+    assert "cache_miss" not in device[0]["args"]
+    assert device[0]["args"]["host_fallback"] is True
+    assert device[0]["args"]["pallas_downgrade"] is False
 
 
 def test_parse_accepts_bare_event_list():
@@ -198,28 +206,89 @@ def test_snapshot_cadence_respects_metrics_every():
     assert dense.metrics.n_snapshots > sparse.metrics.n_snapshots > 0
 
 
+# ---- host spans -------------------------------------------------------------
+
+
+def test_span_without_session_is_a_noop_and_imports_no_jax():
+    """Off, every span site gets one shared no-op; on, a span still times
+    and records without loading jax (runs in a fresh interpreter)."""
+    script = textwrap.dedent(
+        """
+        import sys
+        from repro import obs
+
+        assert obs.span("rd.prep") is obs.NO_SPAN
+        assert obs.device_span("rd-device", (8, 128, 2)) is obs.NO_SPAN
+        with obs.span("rd.prep") as sp, obs.device_span("rd-device", ()) as dev:
+            assert sp is None and dev is None
+        with obs.observe() as s:
+            with obs.span("tick.service"):
+                pass
+        assert s.metrics.histogram("tick.service.us").count == 1
+        assert "jax" not in sys.modules, "repro.obs imported jax"
+        print("ok")
+        """
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", script],
+        capture_output=True,
+        text=True,
+        timeout=120,
+        env={**os.environ, "PYTHONPATH": _SRC, "JAX_PLATFORMS": "cpu"},  # reprolint: disable=R002 passthrough to a subprocess, no backend choice read
+    )
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert proc.stdout.strip() == "ok"
+
+
+def test_span_fills_its_histogram_and_the_ring_buffer():
+    with obs.observe() as s:
+        with obs.span("rd.prep"):
+            with s.span("sched.admit", hist=None):
+                pass
+        with s.span("tick.service", hist="tick.other.us"):
+            pass
+    m = s.metrics
+    assert m.histogram("rd.prep.us").count == 1
+    assert m.histogram("sched.admit.us") is None  # hist=None: ring only
+    assert m.histogram("tick.other.us").count == 1
+    spans = [r for r in s.trace.records() if r[0] == trace_mod.SPAN_HOST]
+    names = [s.trace.strings[r[3]] for r in spans]
+    assert names == ["sched.admit", "rd.prep", "tick.service"]  # close order
+    inner, outer = spans[0], spans[1]
+    assert outer[1] <= inner[1] and inner[1] + inner[2] <= outer[1] + outer[2]
+    with obs.observe(trace=False) as quiet:
+        with obs.span("rd.prep"):
+            pass
+    assert quiet.trace is None
+    assert quiet.metrics.histogram("rd.prep.us").count == 1
+
+
 # ---- device profiler --------------------------------------------------------
 
 
 def test_device_profiler_splits_compile_and_exec():
+    """Every call lands in ``exec_us``: compiles are counted from
+    ``jax.monitoring``, never guessed from a signature's first call."""
     s = ObsSession()
     prof = s.device
+    assert isinstance(prof, DeviceProfiler)
     sig = (16, 32, 1)
     for _ in range(3):
-        prof.record("wf-groups", sig, prof.start())
-    prof.record("rd-device", (8, 4, 2), prof.start(), fallback=True)
+        with prof.span("wf-groups", sig):
+            pass
+    with prof.span("rd-device", (8, 4, 2)) as dev:
+        dev.fallback = True
     m = s.metrics
     assert m.counter("device.wf-groups.calls") == 3
-    assert m.counter("device.wf-groups.compiles") == 1
-    assert m.histogram("device.wf-groups.compile_us").count == 1
-    assert m.histogram("device.wf-groups.exec_us").count == 2
+    assert m.counter("device.wf-groups.compiles") == 0
+    assert m.histogram("device.wf-groups.compile_us") is None
+    assert m.histogram("device.wf-groups.exec_us").count == 3
     assert m.counter("device.rd-device.host_fallback") == 1
     device_events = [
         r for r in s.trace.records() if r[0] == trace_mod.INST_DEVICE
     ]
     assert len(device_events) == 4
-    assert device_events[0][4] & 1  # first wf-groups call is a cache miss
-    assert not (device_events[2][4] & 1)  # third hits the jit cache
+    assert not any(r[4] & 1 for r in device_events)  # no cache-miss bit
     assert device_events[3][4] & 2  # the rd fallback is flagged
 
 
@@ -303,6 +372,79 @@ def test_observed_engine_run_is_schedule_identical(scenario, ordering):
     assert _result_key(observed) == _result_key(plain)
     assert s.metrics.counter("jobs.arrived") == len(jobs)
     assert s.metrics.counter("jobs.completed") == len(plain.jct)
+
+
+def _rd_problems():
+    rng = np.random.default_rng(11)
+    out = []
+    for n in range(4):
+        m = 12
+        groups = tuple(
+            TaskGroup(
+                int(rng.integers(1, 9)),
+                tuple(sorted(rng.choice(m, size=int(rng.integers(2, 5)), replace=False).tolist())),
+            )
+            for _ in range(1 + n)
+        )
+        out.append(AssignmentProblem(
+            busy=rng.integers(0, 4, m).astype(np.int64),
+            mu=rng.integers(1, 4, m).astype(np.int64),
+            groups=groups,
+        ))
+    return out
+
+
+def test_rd_iters_agree_across_device_backends_and_obs_leaves_rd_alone():
+    """The RD program's loop counter reads the same under the jnp strip
+    and the Pallas kernel (interpret mode), one ``rd.iters`` observation
+    per job, single and chained; with the phase spans on, every
+    assignment equals the one made with observability off."""
+    from repro.core.rd_jax import replica_deletion_jax, replica_deletion_jax_chain
+
+    problems = _rd_problems()
+    chain = [dataclasses.replace(p, busy=problems[0].busy) for p in problems]
+    iters = {}
+    for backend in ("jnp", "pallas"):
+        off = [replica_deletion_jax(p, backend=backend).alloc for p in problems]
+        off_chain = [a.alloc for a in replica_deletion_jax_chain(chain, backend=backend)]
+        with obs.observe() as s:
+            on = [replica_deletion_jax(p, backend=backend).alloc for p in problems]
+            on_chain = [
+                a.alloc for a in replica_deletion_jax_chain(chain, backend=backend)
+            ]
+        assert on == off and on_chain == off_chain
+        h = s.metrics.histogram("rd.iters")
+        assert h.count == 2 * len(problems)  # one per job, padded jobs left out
+        iters[backend] = (h.count, h.total, h.max)
+        for phase in ("prep", "wait", "readback", "decode"):
+            assert s.metrics.histogram(f"rd.{phase}.us").count == len(problems) + 1
+        assert s.metrics.counter("device.rd-device.calls") == len(problems)
+        assert s.metrics.counter("device.rd-chain.calls") == 1
+    assert iters["jnp"] == iters["pallas"]
+    assert iters["jnp"][1] >= 2 * len(problems)  # every job ran its loops
+
+
+def test_observed_device_rd_run_is_schedule_identical():
+    """The obs on ≡ off contract with the device RD path and its spans
+    (dispatch phases, ``sched.admit``, tick phases) all firing."""
+    from repro.backend import set_backend
+    from repro.core import Job
+
+    jobs = [
+        Job(job_id=i, arrival=i // 2, groups=p.groups, mu=p.mu)
+        for i, p in enumerate(_rd_problems() * 2)
+    ]
+    with set_backend(rd="jnp"):
+        plain = SchedulingEngine(12, make_policy("rd")).run(jobs)
+        with obs.observe() as s:
+            observed = SchedulingEngine(12, make_policy("rd")).run(jobs)
+    assert _result_key(observed) == _result_key(plain)
+    hists = s.metrics.histograms
+    for name in ("rd.prep.us", "rd.wait.us", "rd.readback.us", "rd.decode.us",
+                 "rd.iters", "sched.overhead_us"):
+        assert hists[name].count > 0, name
+    spans = {s.trace.strings[r[3]] for r in s.trace.records() if r[0] == trace_mod.SPAN_HOST}
+    assert {"sched.admit", "rd.prep", "rd.wait", "rd.readback", "rd.decode"} <= spans
 
 
 def test_observed_online_plane_is_schedule_identical():

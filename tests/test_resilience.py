@@ -18,7 +18,6 @@ import pytest
 
 from repro import obs
 from repro.core import Job, TaskGroup
-from repro.obs.metrics import perf_regressions
 from repro.runtime import (
     ControlPlane,
     RackEvent,
@@ -401,51 +400,3 @@ def test_steal_racing_rack_failure_conserves_jobs():
     ).run(jobs)
     assert len(res.jct) + len(res.failed_jobs) == len(jobs)
 
-
-# ---- perf diff (repro.obs.report --diff) ------------------------------------
-
-
-def _table(mean, compiles):
-    return {
-        "hist.tick.service.us.mean": np.asarray([mean]),
-        "hist.tick.service.us.p99": np.asarray([mean * 2]),
-        "counter.device.wf.compiles": np.asarray([float(compiles)]),
-        "counter.jobs.completed": np.asarray([100.0]),  # not watched
-    }
-
-
-def test_perf_regressions_flags_only_watched_columns():
-    old = _table(10.0, 2)
-    assert perf_regressions(old, _table(10.0, 2)) == []
-    assert perf_regressions(old, _table(19.0, 2)) == []  # under 2x
-    regs = perf_regressions(old, _table(25.0, 2))
-    assert {r["name"] for r in regs} == {
-        "hist.tick.service.us.mean",
-        "hist.tick.service.us.p99",
-    }
-    # compile-count regressions are caught too, other counters ignored
-    regs = perf_regressions(old, _table(10.0, 5))
-    assert [r["name"] for r in regs] == ["counter.device.wf.compiles"]
-    # a column absent from the old run reports an infinite ratio
-    new = dict(_table(10.0, 2), **{
-        "counter.device.rd.compiles": np.asarray([1.0]),
-    })
-    old2 = dict(old, **{"counter.device.rd.compiles": np.asarray([0.0])})
-    regs = perf_regressions(old2, new)
-    assert regs and regs[0]["ratio"] == float("inf")
-    # the noise floor suppresses tiny absolute values
-    assert perf_regressions(old2, new, min_value=1.0) == []
-
-
-def test_report_diff_cli_exit_codes(tmp_path):
-    from repro.obs.report import main
-
-    old = tmp_path / "old.npz"
-    new = tmp_path / "new.npz"
-    np.savez(old, **_table(10.0, 2))
-    np.savez(new, **_table(10.0, 2))
-    assert main(["--diff", str(old), str(new)]) == 0
-    np.savez(new, **_table(50.0, 2))
-    assert main(["--diff", str(old), str(new)]) == 1
-    # a looser threshold lets the same pair pass
-    assert main(["--diff", str(old), str(new), "--threshold", "10"]) == 0

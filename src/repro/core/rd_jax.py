@@ -13,6 +13,8 @@ equivalence class ``(group, surviving servers)``:
   ascending, padded with ``M`` (sorts after every real id); ``A`` is the
   maximum initial availability width, and a class's holder row is
   *static* for its lifetime (deletions spin members into a new slot).
+- ``setkey``: ``(A/2, C)`` the holder rows packed two ids a word, one
+  row per word, as the strip kernel's key block takes them.
 - ``size``/``cnt``/``grp``: ``(C,)`` member count (0 = drained or
   unallocated), replica count, group id.
 - ``m1``/``b1``/``b2``: the cheapest-alternative tie-break triple of
@@ -21,6 +23,12 @@ equivalence class ``(group, surviving servers)``:
   (``dest[c, j]`` = slot holding members of ``c`` after a strip of
   ``holders[c, j]``; ``-1`` = not yet materialized).
 - ``load``/``multi``/``busy_est``: ``(M,)`` delta-updated server state.
+- ``hist``: active classes per (replica count, server), the device
+  twin of the host's count buckets, flat: count ``k``'s row of ``M``
+  servers starts at ``k·Mp`` (``Mp`` = ``M`` in whole 1,024-lane
+  tiles, so rows slice on tile bounds and updates scatter 1-D).  The
+  deletion phase's per-server peek (max active count) is a dense max
+  over its ``A+1`` rows.
 
 One *strip* of server ``m`` is a vectorized select-target →
 bucket-walk → delta-update step: candidates (active, on ``m``, multi-
@@ -28,12 +36,14 @@ copy) sort by the strip key ``(-count, alt, holders-row, group, slot)``
 — within one count bucket every class has the same cardinality, so
 comparing holder rows lexicographically *is* the reference's sorted
 server-tuple order — then a prefix-sum of member counts against the
-quota ``((load-1) mod μ)+1`` yields every class's deletion in one shot,
-and scatters re-home the members (spin-off slots are the lowest empty
-slots, so drained classes are recycled; duplicate ``(group, set)``
-slots reached via different strip paths are exchangeable under the
-total key, so no global dict is needed).  With ``backend="pallas"``
-the sort + prefix walk runs as the fused kernel in
+quota ``((load-1) mod μ)+1`` yields every class's deletion in one shot.
+Each moving class loses at least one member, so at most ``quota ≤ μ``
+classes move, all in a prefix of the sorted order: the delta updates
+read and write those ``_MOVERS`` rows only (spin-off slots are the
+lowest empty slots, so drained classes are recycled; duplicate
+``(group, set)`` slots reached via different strip paths are
+exchangeable under the total key, so no global dict is needed).  With
+``backend="pallas"`` the sort + prefix walk runs as the fused kernel in
 :mod:`repro.kernels.rd` (bitonic network over the slot
 lanes with the multi-row lexicographic key, Hillis–Steele prefix sums —
 the waterlevel kernel's recipe); the surrounding delta updates are
@@ -42,9 +52,10 @@ identical by construction.
 
 Slot capacity ``C`` is fixed per dispatch (power-of-two padded) and
 sized so it cannot run out (:func:`rd_slot_capacity`).  Should a
-smaller capacity ever be exceeded, the program sets an ``overflow`` flag
-and the host adapter re-runs the instance through host RD, so results
-stay correct for any input.
+smaller capacity ever be exceeded, or a strip move more than
+``_MOVERS`` classes (μ past the contract's ``RD_ENV_MU_MAX``), the
+program sets an ``overflow`` flag and the host adapter re-runs the
+instance through host RD, so results stay correct for any input.
 
 Every backend is *assignment-identical* to the executable specification
 in :mod:`repro.core.rd_reference` under the documented deterministic
@@ -132,7 +143,7 @@ class _RDDev(NamedTuple):
     """The dense class-compressed state carried through the while loops."""
 
     holders: jax.Array  # (C, A) i32, sorted asc, pad = M
-    setkey: jax.Array  # (C, A/2) i32 packed holder row (strip sort key)
+    setkey: jax.Array  # (A/2, C) i32 packed holder rows (strip sort key)
     dest: jax.Array  # (C, A) i32 spin-off pointers, -1 = none
     size: jax.Array  # (C,) i32 members (0 = drained / unallocated)
     cnt: jax.Array  # (C,) i32 replica count (static per slot)
@@ -143,7 +154,8 @@ class _RDDev(NamedTuple):
     load: jax.Array  # (M,) i32
     multi: jax.Array  # (M,) i32 multi-copy population per server
     busy_est: jax.Array  # (M,) i32  b_m + ceil(load_m/mu_m)
-    overflow: jax.Array  # () bool — slot capacity exceeded, result invalid
+    hist: jax.Array | None  # ((A+1)·Mp,) i32 classes per count; dedup: None
+    overflow: jax.Array  # () bool — slots or movers exceeded, result invalid
 
 
 def _alt_triple(
@@ -154,15 +166,13 @@ def _alt_triple(
     Rows are sorted ascending by id, so ``argmin``'s first-occurrence
     convention reproduces the reference's first-strict-min holder.
     """
-    busy_ext = jnp.concatenate(
-        [busy0.astype(jnp.int32), jnp.full((1,), _BIG, jnp.int32)]
-    )
-    hb = busy_ext[jnp.minimum(holders, m_servers)]  # (C, A); pads -> _BIG
-    rows = jnp.arange(holders.shape[0])
-    j1 = jnp.argmin(hb, axis=1)
-    b1 = hb[rows, j1]
-    m1 = holders[rows, j1]
-    b2 = jnp.min(hb.at[rows, j1].set(_BIG), axis=1)
+    real = holders < m_servers
+    hb = jnp.where(real, busy0[jnp.where(real, holders, 0)], _BIG)  # pads: _BIG
+    j1 = jnp.argmin(hb, axis=1)[:, None]
+    b1 = jnp.take_along_axis(hb, j1, axis=1)[:, 0]
+    m1 = jnp.take_along_axis(holders, j1, axis=1)[:, 0]
+    col = jnp.arange(holders.shape[1], dtype=j1.dtype)
+    b2 = jnp.min(jnp.where(col == j1, _BIG, hb), axis=1)
     return m1, b1, b2
 
 
@@ -172,15 +182,15 @@ def _strip_order_jnp(
     """Slot permutation realizing the strip key via ``jnp.lexsort``.
 
     Key (most significant first): masked ``-count`` (``_BIG`` parks
-    non-candidates past every candidate), alt, the packed holder row
+    non-candidates past every candidate), alt, the packed holder rows
     (ascending-lexicographic ≡ the reference's sorted server-tuple
     order within a count bucket, where cardinalities are equal), group,
     slot index — a total order, so the Pallas sorting network (same key,
     unique final tie) yields the identical permutation.
     """
-    c_slots, p_words = setkey.shape
+    p_words, c_slots = setkey.shape
     keys = (jnp.arange(c_slots, dtype=jnp.int32), grp)
-    keys += tuple(setkey[:, a] for a in range(p_words - 1, -1, -1))
+    keys += tuple(setkey[a] for a in range(p_words - 1, -1, -1))
     keys += (altv, neg_key)
     return jnp.lexsort(keys)
 
@@ -193,21 +203,22 @@ def _strip(
     *,
     use_pallas: bool,
     interpret: bool,
-) -> tuple[_RDDev, jax.Array]:
+) -> tuple[_RDDev, jax.Array, jax.Array]:
     """Delete up to ``((load-1) mod μ)+1`` multi-copy replicas from ``m``.
 
     The reference's sequential max-key pops collapse into one sort +
     prefix-sum (keys are static within a strip — deleted members leave
-    ``m``); every delta update is a masked scatter.  Returns the state
-    and the number of replicas removed.
+    ``m``).  The takes are a prefix of the sorted order and each moving
+    class gives at least one member, so the movers are the first
+    ``_MOVERS`` sorted slots; every delta update reads and writes their
+    rows and their spin-offs' only.  Returns the state, the number of
+    replicas removed and the number of classes moved.
     """
     c_slots = st.holders.shape[0]
     m_servers = st.load.shape[0]
-    rows = jnp.arange(c_slots, dtype=jnp.int32)
     quota = ((st.load[m] - 1) % mu[m]) + 1
 
-    is_m = st.holders == m  # (C, A)
-    onm = is_m.any(axis=1)
+    onm = (st.holders == m).any(axis=1)
     cand = onm & (st.size > 0) & (st.cnt >= 2)
     altv = jnp.where(st.m1 == m, st.b2, st.b1)
     neg_key = jnp.where(cand, -st.cnt, _BIG)
@@ -217,7 +228,7 @@ def _strip(
         from repro.kernels.rd import rd_strip_takes_pallas
 
         keyblock = jnp.concatenate(
-            [neg_key[None], altv[None], st.setkey.T, st.grp[None]]
+            [neg_key[None], altv[None], st.setkey, st.grp[None]]
         )
         take_sorted, order = rd_strip_takes_pallas(
             keyblock, st.size, quota, interpret=interpret
@@ -227,59 +238,76 @@ def _strip(
         s_sorted = jnp.where(neg_key[order] != _BIG, st.size[order], 0)
         prev = jnp.cumsum(s_sorted) - s_sorted
         take_sorted = jnp.clip(quota - prev, 0, s_sorted)
-    take = jnp.zeros(c_slots, jnp.int32).at[order].set(take_sorted)
+
+    # --- the movers: the first q sorted slots ----------------------------
+    q = min(_MOVERS, c_slots)
+    src, take = order[:q], take_sorted[:q]
+    mv = take > 0
     removed = take.sum()
+    # a quota past _MOVERS (μ outside the contract) cannot be applied
+    overflow = st.overflow | (take_sorted[q:] > 0).any()
+    h = st.holders[src]  # (q, A)
+    cnt_s = st.cnt[src]
+    is_m = h == m
+    jpos = jnp.argmax(is_m, axis=1)  # m's column
 
     # --- re-home the deleted members (spin-off slots, O(1) per class) ---
-    mv = take > 0
-    jpos = jnp.argmax(is_m, axis=1)  # m's column (valid where onm)
-    d_exist = st.dest[rows, jpos]
+    # An existing destination still holds members, so it is never free:
+    # it drains only in a strip of one of its holders, where its source
+    # (a superset, so a higher count) sorts before it and drains first,
+    # and a drained class refills only from its own source, up to an
+    # initial class that nothing refills.
+    d_exist = st.dest[src, jpos]
     need_new = mv & (d_exist < 0)
-    # spin-offs take the lowest free slots: empty, and not the existing
-    # (possibly drained) destination of this strip's moves
-    reused = jnp.zeros(c_slots, bool).at[
-        jnp.where(mv & ~need_new, d_exist, c_slots)
-    ].set(True, mode="drop")
-    free = (st.size == 0) & ~reused
-    (free_ids,) = jnp.nonzero(free, size=c_slots, fill_value=c_slots)
-    d_new = free_ids[jnp.clip(jnp.cumsum(need_new) - 1, 0, c_slots - 1)]
+    # spin-offs take the lowest empty slots, in their sources' slot order
+    n_free = jnp.cumsum(st.size == 0)
+    rank = ((src[None, :] < src[:, None]) & need_new[None, :]).sum(axis=1)
+    d_new = (n_free[None, :] <= rank[:, None]).sum(axis=1)  # C: none left
     d = jnp.where(need_new, d_new, d_exist)
-    overflow = st.overflow | (need_new.sum() > free.sum())
+    overflow = overflow | (need_new.sum() > n_free[-1])
 
     # spun holder row: drop the (unique) entry equal to m, shift left
     shifted = jnp.concatenate(
-        [st.holders[:, 1:], jnp.full((c_slots, 1), m_servers, jnp.int32)],
-        axis=1,
+        [h[:, 1:], jnp.full((q, 1), m_servers, jnp.int32)], axis=1
     )
-    spun = jnp.where(jnp.cumsum(is_m, axis=1) > 0, shifted, st.holders)
+    spun = jnp.where(jnp.cumsum(is_m, axis=1) > 0, shifted, h)
 
     tgt_new = jnp.where(need_new, d, c_slots)  # OOB rows are dropped
     holders = st.holders.at[tgt_new].set(spun, mode="drop")
-    setkey = st.setkey.at[tgt_new].set(_pack_setkey(spun), mode="drop")
-    grp = st.grp.at[tgt_new].set(st.grp, mode="drop")
-    cnt = st.cnt.at[tgt_new].set(st.cnt - 1, mode="drop")
+    setkey = st.setkey.at[:, tgt_new].set(_pack_setkey(spun).T, mode="drop")
+    grp = st.grp.at[tgt_new].set(st.grp[src], mode="drop")
+    cnt = st.cnt.at[tgt_new].set(cnt_s - 1, mode="drop")
     nm1, nb1, nb2 = _alt_triple(spun, busy0, m_servers)
     m1 = st.m1.at[tgt_new].set(nm1, mode="drop")
     b1 = st.b1.at[tgt_new].set(nb1, mode="drop")
     b2 = st.b2.at[tgt_new].set(nb2, mode="drop")
-    # a recycled slot starts a new class: pointers into it from earlier
-    # classes, and its own spin-off pointers, are stale
-    fresh = jnp.zeros(c_slots + 1, bool).at[tgt_new].set(True)
-    dest = jnp.where(fresh[jnp.where(st.dest < 0, c_slots, st.dest)], -1, st.dest)
-    dest = dest.at[tgt_new].set(-1, mode="drop")
-    dest = dest.at[jnp.where(mv, rows, c_slots), jpos].set(d, mode="drop")
+    # a recycled slot starts a new class: its own spin-off pointers are stale
+    dest = st.dest.at[tgt_new].set(-1, mode="drop")
+    dest = dest.at[jnp.where(mv, src, c_slots), jpos].set(d, mode="drop")
 
     tgt_mv = jnp.where(mv, d, c_slots)
-    size = (st.size - take).at[tgt_mv].add(take, mode="drop")
+    drained = mv & (st.size[src] == take)
+    size = st.size.at[src].add(-take).at[tgt_mv].add(take, mode="drop")
 
     # --- delta-update the server vectors -------------------------------
     multi = st.multi.at[m].add(-removed)
     # members of a count-2 class became sole-copy on their last holder
-    c2 = mv & (st.cnt == 2)
-    last = spun[:, 0]
-    multi = multi.at[jnp.where(c2, last, m_servers)].add(-take, mode="drop")
+    c2 = mv & (cnt_s == 2)
+    multi = multi.at[jnp.where(c2, spun[:, 0], m_servers)].add(-take, mode="drop")
     load = st.load.at[m].add(-removed)
     busy_est = st.busy_est.at[m].set(busy0[m] + _ceil_div(load[m], mu[m]))
+
+    hist = st.hist
+    if hist is not None:
+        # a drained mover leaves every holder's count bucket; a new
+        # spin-off enters its holders' buckets one count lower
+        rows = jnp.concatenate([h, spun])
+        on = jnp.concatenate([drained, need_new])
+        kcnt = jnp.concatenate([cnt_s, cnt_s - 1])
+        delta = jnp.repeat(jnp.asarray([-1, 1], jnp.int32), q)
+        hist = hist.at[_hist_index(hist, kcnt, rows, on, m_servers)].add(
+            jnp.broadcast_to(delta[:, None], rows.shape), mode="drop"
+        )
 
     return (
         _RDDev(
@@ -295,28 +323,87 @@ def _strip(
             load=load,
             multi=multi,
             busy_est=busy_est,
+            hist=hist,
             overflow=overflow,
         ),
         removed,
+        mv.sum(dtype=jnp.int32),
     )
 
 
-def _peek_vec(st: _RDDev) -> jax.Array:
-    """Max replica count among active classes, per server (scatter-max)."""
-    m_servers = st.load.shape[0]
-    vals = jnp.where(st.size > 0, st.cnt, 0)[:, None]
-    vals = jnp.broadcast_to(vals, st.holders.shape)
-    return (
-        jnp.zeros(m_servers, jnp.int32)
-        .at[st.holders.reshape(-1)]
-        .max(vals.reshape(-1), mode="drop")
-    )
+def _hist_stride(m_servers: int) -> int:
+    """Row stride of the flat count-bucket table: M in whole 1-D tiles."""
+    return -(-m_servers // 1024) * 1024
+
+
+def _hist_index(
+    hist: jax.Array,
+    cnt: jax.Array,
+    holders: jax.Array,
+    on: jax.Array,
+    m_servers: int,
+) -> jax.Array:
+    """Flat bucket index of each (row's count, holder); pads and rows
+    not ``on`` index past the table, so a ``drop`` scatter skips them."""
+    idx = cnt[:, None] * _hist_stride(m_servers) + holders
+    return jnp.where(on[:, None] & (holders < m_servers), idx, hist.shape[0])
+
+
+def _peek_vec(hist: jax.Array, m_servers: int) -> jax.Array:
+    """Max replica count among active classes, per server (0 = none)."""
+    stride = _hist_stride(m_servers)
+    peek = jnp.zeros(m_servers, jnp.int32)
+    for k in range(1, hist.shape[0] // stride):
+        peek = jnp.where(hist[k * stride : k * stride + m_servers] > 0, k, peek)
+    return peek
 
 
 def _refine_max(mask: jax.Array, key: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Narrow ``mask`` to the entries attaining ``max(key over mask)``."""
     best = jnp.max(jnp.where(mask, key, jnp.iinfo(jnp.int32).min))
     return mask & (key == best), best
+
+
+def _init_state(
+    busy0: jax.Array,
+    mu: jax.Array,
+    holders0: jax.Array,
+    size0: jax.Array,
+    cnt0: jax.Array,
+    grp0: jax.Array,
+) -> _RDDev:
+    """The loops' starting state: one slot per task group (one pass of
+    C × A scatters builds the per-server vectors and count buckets)."""
+    c_slots, a_max = holders0.shape
+    m_servers = busy0.shape[0]
+    m1, b1, b2 = _alt_triple(holders0, busy0, m_servers)
+
+    def per_server(vals):
+        return jnp.zeros(m_servers, jnp.int32).at[holders0].add(
+            jnp.broadcast_to(vals[:, None], holders0.shape), mode="drop"
+        )
+
+    load = per_server(size0)
+    hist = jnp.zeros((a_max + 1) * _hist_stride(m_servers), jnp.int32)
+    hist = hist.at[_hist_index(hist, cnt0, holders0, size0 > 0, m_servers)].add(
+        1, mode="drop"
+    )
+    return _RDDev(
+        holders=holders0,
+        setkey=_pack_setkey(holders0).T,
+        dest=jnp.full((c_slots, a_max), -1, jnp.int32),
+        size=size0,
+        cnt=cnt0,
+        grp=grp0,
+        m1=m1,
+        b1=b1,
+        b2=b2,
+        load=load,
+        multi=per_server(jnp.where(cnt0 >= 2, size0, 0)),
+        busy_est=busy0 + _ceil_div(load, mu),
+        hist=hist,
+        overflow=jnp.asarray(False),
+    )
 
 
 def _rd_core(
@@ -329,41 +416,19 @@ def _rd_core(
     *,
     use_pallas: bool,
     interpret: bool,
-) -> tuple[_RDDev, jax.Array]:
+) -> tuple[_RDDev, jax.Array, jax.Array]:
     """Run the whole RD (deletion + dedup) for one instance on device;
-    returns the final state and the iterations the two loops ran (int32:
-    deletion iterations, strip or not, plus dedup strips)."""
-    c_slots, a_max = holders0.shape
+    returns the final state, the iterations the two loops ran (deletion
+    iterations, strip or not, plus dedup strips) and the classes their
+    strips moved (int32 each)."""
     m_servers = busy0.shape[0]
     busy0 = busy0.astype(jnp.int32)
     mu = mu.astype(jnp.int32)
-
-    m1, b1, b2 = _alt_triple(holders0, busy0, m_servers)
-    flat = holders0.reshape(-1)
-    bsize = jnp.broadcast_to(size0[:, None], holders0.shape).reshape(-1)
-    load = jnp.zeros(m_servers, jnp.int32).at[flat].add(bsize, mode="drop")
-    bmulti = jnp.broadcast_to(
-        jnp.where(cnt0 >= 2, size0, 0)[:, None], holders0.shape
-    ).reshape(-1)
-    multi = jnp.zeros(m_servers, jnp.int32).at[flat].add(bmulti, mode="drop")
-    st = _RDDev(
-        holders=holders0,
-        setkey=_pack_setkey(holders0),
-        dest=jnp.full((c_slots, a_max), -1, jnp.int32),
-        size=size0,
-        cnt=cnt0,
-        grp=grp0,
-        m1=m1,
-        b1=b1,
-        b2=b2,
-        load=load,
-        multi=multi,
-        busy_est=busy0 + _ceil_div(load, mu),
-        overflow=jnp.asarray(False),
-    )
+    st = _init_state(busy0, mu, holders0, size0, cnt0, grp0)
     strip = functools.partial(
         _strip, busy0=busy0, mu=mu, use_pallas=use_pallas, interpret=interpret
     )
+    zero = jnp.asarray(0, jnp.int32)
 
     # ---- deletion phase --------------------------------------------------
     # One iteration = one strip, with the level sweep folded in: when the
@@ -374,11 +439,11 @@ def _rd_core(
     # still-valid sweep targets — exactly what the host's lazy re-ranking
     # heap realizes (stale keys are optimistic and validated at pop).
     def del_cond(carry):
-        st, targets0, best, done, _ = carry
+        st, targets0, best, done, _, _ = carry
         return ~done & ~st.overflow
 
     def del_body(carry):
-        st, targets0, best, done, iters = carry
+        st, targets0, best, done, iters, moved = carry
         valid = targets0 & (st.busy_est == best) & (st.load > 0)
         new_sweep = ~valid.any()
         held = st.load > 0
@@ -392,15 +457,14 @@ def _rd_core(
         done_now = new_sweep & (
             (nbest < 0) | (ntargets & (st.multi == 0)).any()
         )
-        peek = _peek_vec(st)
-        mask, p = _refine_max(valid, peek)
+        mask, p = _refine_max(valid, _peek_vec(st.hist, m_servers))
         mask, _ = _refine_max(mask, busy0)
         m = jnp.argmax(mask)  # ties fall to the smallest id
         do_strip = ~done_now & (p >= 2)
-        st, removed = jax.lax.cond(
+        st, removed, n_moved = jax.lax.cond(
             do_strip,
             lambda s: strip(s, m),
-            lambda s: (s, jnp.asarray(0, jnp.int32)),
+            lambda s: (s, zero, zero),
             st,
         )
         # a strip that ran out of quota drained m's multi-copy classes;
@@ -412,43 +476,45 @@ def _rd_core(
             | (do_strip & (removed == 0))
             | (do_strip & (tmask & (st.multi == 0)).any())
         )
-        return st, targets0, best, done, iters + 1
+        return st, targets0, best, done, iters + 1, moved + n_moved
 
-    st, _, _, _, iters = jax.lax.while_loop(
+    st, _, _, _, iters, moved = jax.lax.while_loop(
         del_cond,
         del_body,
         (st, jnp.zeros(m_servers, bool), jnp.asarray(-2, jnp.int32),
-         jnp.asarray(False), jnp.asarray(0, jnp.int32)),
+         jnp.asarray(False), zero, zero),
     )
 
     # ---- final dedup phase ----------------------------------------------
     # One strip per iteration from the busiest multi-copy holder,
     # (busy_est, busy0, id) descending — the reference's lexsort pick.
+    # It reads no peek, so it carries no count buckets.
     def dd_cond(carry):
-        st, _ = carry
+        st, _, _ = carry
         return (st.multi > 0).any() & ~st.overflow
 
     def dd_body(carry):
-        st, iters = carry
+        st, iters, moved = carry
         mask = st.multi > 0
         mask, _ = _refine_max(mask, st.busy_est)
         mask, _ = _refine_max(mask, busy0)
-        m_servers_ = st.load.shape[0]
-        m = m_servers_ - 1 - jnp.argmax(mask[::-1])  # ties -> largest id
-        st, _ = strip(st, m)
-        return st, iters + 1
+        m = m_servers - 1 - jnp.argmax(mask[::-1])  # ties -> largest id
+        st, _, n_moved = strip(st, m)
+        return st, iters + 1, moved + n_moved
 
-    return jax.lax.while_loop(dd_cond, dd_body, (st, iters))
+    return jax.lax.while_loop(
+        dd_cond, dd_body, (st._replace(hist=None), iters, moved)
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def _rd_device(busy0, mu, holders0, size0, cnt0, grp0, *, use_pallas,
                interpret):
-    st, iters = _rd_core(
+    st, iters, moved = _rd_core(
         busy0, mu, holders0, size0, cnt0, grp0,
         use_pallas=use_pallas, interpret=interpret,
     )
-    return st.size, st.cnt, st.grp, st.holders[:, 0], st.overflow, iters
+    return st.size, st.cnt, st.grp, st.holders[:, 0], st.overflow, iters, moved
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
@@ -459,13 +525,14 @@ def _rd_device_chain(busy0, mu, holders0, size0, cnt0, grp0, *,
     The RD twin of :func:`repro.core.wf_jax.water_fill_chain`: job ``i+1``
     sees ``b_m + ⌈load_m^i/μ_m^i⌉`` (eq. 2) exactly as if the burst were
     admitted one job at a time.  Padded jobs carry zero slots and commit
-    nothing.  Outputs are per job, the loops' iteration count included.
+    nothing.  Outputs are per job, the loops' iteration and moved-class
+    counts included.
     """
     m_servers = busy0.shape[0]
 
     def job_step(busy, inp):
         h0, s0, c0, g0, mu_j = inp
-        st, iters = _rd_core(
+        st, iters, moved = _rd_core(
             busy, mu_j, h0, s0, c0, g0,
             use_pallas=use_pallas, interpret=interpret,
         )
@@ -478,7 +545,7 @@ def _rd_device_chain(busy0, mu, holders0, size0, cnt0, grp0, *,
             loads > 0, _ceil_div(loads, mu_j.astype(jnp.int32)), 0
         )
         return busy_next, (st.size, st.cnt, st.grp, st.holders[:, 0],
-                           st.overflow, iters)
+                           st.overflow, iters, moved)
 
     _, outs = jax.lax.scan(
         job_step,
@@ -528,12 +595,14 @@ def _decode(
     return result
 
 
-def _observe_iters(iters) -> None:
-    """One ``rd.iters`` observation per job: the device loops' iterations."""
+def _observe_loops(iters, moved) -> None:
+    """One ``rd.iters`` and one ``rd.moved`` observation per job: the
+    device loops' iterations and the classes their strips moved."""
     session = _obs_active()
     if session is not None:
-        for n in np.atleast_1d(iters):
+        for n, k in zip(np.atleast_1d(iters), np.atleast_1d(moved)):
             session.metrics.observe("rd.iters", int(n))
+            session.metrics.observe("rd.moved", int(k))
 
 
 def _resolve_device(backend: str, c_cap: int, a_pad: int) -> tuple[bool, bool]:
@@ -566,6 +635,9 @@ def _resolve_device(backend: str, c_cap: int, a_pad: int) -> tuple[bool, bool]:
 RD_ENV_BUSY0_MAX = 1 << 20  # pre-burst busy time per server
 RD_ENV_TASKS_MAX = 1 << 20  # tasks per job
 RD_ENV_MU_MAX = 1 << 4  # per-server tasks/slot (μ)
+# rows a strip's delta updates touch: a strip moves at most quota ≤ μ
+# classes (a larger quota sets ``overflow`` and re-runs on the host)
+_MOVERS = _next_pow2(RD_ENV_MU_MAX)
 RD_ENV_CHAIN_JOBS_MAX = 64  # jobs per chained same-slot burst
 
 
@@ -606,12 +678,17 @@ def _rd_range_claims(geom: dict, *, chain_jobs: int = 1) -> list[RangeClaim]:
     # at most ⌈load/μ⌉ ≤ load ≤ its task total (members are homed at
     # exactly one primary holder, so per-server loads sum to ≤ tasks)
     busy_est = busy0 + Interval(0, chain_jobs) * tasks
+    _, a_pad = _rd_abstract_geometry(geom["m"], geom["k"], geom["a"], geom["s"])
     return [
         RangeClaim(
             "holder id field (pad id = M)", server_id, bits=_PACK_BITS
         ),
         RangeClaim("packed setkey word ((id << 15) | id)", packed, bits=30),
         RangeClaim("per-server load scatter", tasks),
+        RangeClaim(
+            "count-bucket index (count · Mp + id)",
+            Interval(0, (a_pad + 1) * _hist_stride(m)),
+        ),
         RangeClaim("strip quota ((load-1) mod μ + 1)", Interval(1, RD_ENV_MU_MAX)),
         RangeClaim("eq. 2 busy estimate", busy_est),
         RangeClaim(
@@ -698,9 +775,9 @@ def replica_deletion_jax(
 
     Same assignment as :func:`repro.core.rd.replica_deletion` and the
     reference oracle (parity-tested); ``backend`` picks the strip
-    engine (``jnp`` | ``pallas``).  A slot-capacity overflow (see
-    :func:`rd_slot_capacity`) transparently re-runs the instance on the
-    host path.
+    engine (``jnp`` | ``pallas``).  An overflow (slot capacity, see
+    :func:`rd_slot_capacity`, or a strip quota past ``_MOVERS``)
+    transparently re-runs the instance on the host path.
     """
     del seed  # deterministic; retained for API compatibility
     if problem.n_servers > RD_DEVICE_MAX_M:
@@ -718,7 +795,7 @@ def replica_deletion_jax(
         max(2, max((len(g.servers) for g in problem.groups), default=1))
     )
     use_pallas, interpret = _resolve_device(backend, c_cap, a_pad)
-    size_f, cnt_f, grp_f, srv_f, overflow, iters = phased_call(
+    size_f, cnt_f, grp_f, srv_f, overflow, iters, moved = phased_call(
         "rd",
         "rd-device",
         (problem.n_servers, c_cap, a_pad),  # the kernelcheck key
@@ -733,9 +810,9 @@ def replica_deletion_jax(
         downgrade=backend == "pallas" and not use_pallas,
         fallback=lambda outs: bool(outs[4]),
     )
-    if overflow:  # only under a capacity forced smaller — host re-run
+    if overflow:  # a capacity forced smaller, or μ past the contract
         return replica_deletion(problem)
-    _observe_iters(iters)
+    _observe_loops(iters, moved)
     with _obs_span("rd.decode"):
         return _decode(problem, size_f, cnt_f, grp_f, srv_f)
 
@@ -826,7 +903,7 @@ def replica_deletion_jax_chain(
             mu[i] = p.mu
         return np.asarray(base, np.int32), mu, holders, size, cnt, grp
 
-    size_f, cnt_f, grp_f, srv_f, overflow, iters = phased_call(
+    size_f, cnt_f, grp_f, srv_f, overflow, iters, moved = phased_call(
         "rd",
         "rd-chain",
         (m, c_cap, a_pad, b_pad),  # the kernelcheck key
@@ -846,7 +923,7 @@ def replica_deletion_jax_chain(
         return host_commit_walk(problems)
     from .reorder import commit_busy
 
-    _observe_iters(iters[: len(problems)])
+    _observe_loops(iters[: len(problems)], moved[: len(problems)])
     with _obs_span("rd.decode"):
         busy = np.asarray(base)
         out: list[Assignment] = []

@@ -17,7 +17,8 @@ Naming convention (``.``-separated, catalogued in
 depths, ``busy.*`` eq. 2 levels, ``locality.*`` hit tiers, ``steal.*`` /
 ``spec.*`` outcome accounting, ``placement.*`` churn, ``serve.*``
 latency, ``device.<kind>.*`` dispatch profiling, ``<span>.us`` host
-spans, ``rd.iters`` the RD device loop's iterations per job.
+spans, ``rd.iters`` / ``rd.moved`` the RD device loops' iterations and
+the classes their strips moved, per job.
 """
 
 from __future__ import annotations

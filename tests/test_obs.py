@@ -424,6 +424,41 @@ def test_rd_iters_agree_across_device_backends_and_obs_leaves_rd_alone():
     assert iters["jnp"][1] >= 2 * len(problems)  # every job ran its loops
 
 
+def test_rd_moved_is_observed_once_per_job(monkeypatch):
+    """``rd.moved``, the classes the RD program's strips moved, is one
+    observation per job, single and chained; a job moves at most μ
+    classes a strip, and the total is the same with observability off."""
+    from repro.core import rd_jax
+
+    problems = _rd_problems()
+    chain = [dataclasses.replace(p, busy=problems[0].busy) for p in problems]
+    loops = []
+    observe = rd_jax._observe_loops
+
+    def _spy(iters, moved):
+        loops.extend(zip(np.atleast_1d(iters).tolist(), np.atleast_1d(moved).tolist()))
+        observe(iters, moved)
+
+    monkeypatch.setattr(rd_jax, "_observe_loops", _spy)
+
+    def run():
+        loops.clear()
+        for p in problems:
+            rd_jax.replica_deletion_jax(p)
+        rd_jax.replica_deletion_jax_chain(chain)
+        return list(loops)
+
+    off = run()
+    with obs.observe() as s:
+        on = run()
+    assert on == off
+    assert len(off) == 2 * len(problems)  # one per job, padded jobs left out
+    for (iters, moved), p in zip(off, problems + chain):
+        assert 0 < moved <= int(p.mu.max()) * iters
+    h = s.metrics.histogram("rd.moved")
+    assert (h.count, h.total) == (len(off), sum(m for _, m in off))
+
+
 def test_observed_device_rd_run_is_schedule_identical():
     """The obs on ≡ off contract with the device RD path and its spans
     (dispatch phases, ``sched.admit``, tick phases) all firing."""

@@ -23,7 +23,9 @@ kernel's sort order is already pinned to the jnp path by the shared key
 construction (see ``test_kernels.py`` for the kernel-level twin).
 """
 
+import functools
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -295,6 +297,286 @@ def test_device_rejects_oversized_cluster():
     # the auto dispatcher silently stays on host instead
     host = replica_deletion(problem)
     assert replica_deletion_auto(problem).alloc == host.alloc
+
+
+# ---- the compact strip: movers, recycled slots, the μ bound -----------------
+
+
+def _one_member_classes(n, mu):
+    """``n`` one-member classes, each on server 0 and one other server,
+    with server 0 the busiest: its first strip (load ``n``) moves one
+    member from each of ``((n-1) mod μ)+1`` classes."""
+    m = 20
+    return AssignmentProblem(
+        busy=np.r_[9, np.zeros(m - 1, np.int64)],
+        mu=np.full(m, mu),
+        groups=tuple(TaskGroup(1, (0, 1 + g % (m - 1))) for g in range(n)),
+    )
+
+
+# Its early spin-offs drain and their slots are recycled while the
+# recycled slot's row still holds the previous class's spin-off pointers
+# (eleven such strips): a run that followed them misplaces members.
+_RECYCLED = AssignmentProblem(
+    busy=np.array([6, 0, 4, 0, 2, 3, 3, 3]),
+    mu=np.array([1, 1, 1, 1, 3, 2, 2, 1]),
+    groups=(
+        TaskGroup(8, (0, 1, 2)),
+        TaskGroup(2, (3, 4, 5, 6)),
+        TaskGroup(6, (1, 5, 6)),
+        TaskGroup(5, (0, 2, 5, 7)),
+    ),
+)
+
+_COMPACT_CASES = {
+    "many_movers": lambda: _one_member_classes(32, mu=16),
+    "recycled_slot": lambda: _RECYCLED,
+}
+
+
+def _device_outputs(problem, backend="jnp"):
+    """The single-instance device program's raw outputs for ``problem``."""
+    from repro.core import rd_jax
+
+    c_cap = rd_jax.rd_slot_capacity(problem)
+    a_pad = rd_jax._next_pow2(max(2, max(len(g.servers) for g in problem.groups)))
+    use_pallas, interpret = rd_jax._resolve_device(backend, c_cap, a_pad)
+    return rd_jax._rd_device(
+        np.asarray(problem.busy, np.int32),
+        np.asarray(problem.mu, np.int32),
+        *rd_jax._dense_instance(problem, c_cap, a_pad),
+        use_pallas=use_pallas,
+        interpret=interpret,
+    )
+
+
+def _assert_chain_matches_sequential_host(problems, backend):
+    from repro.core.rd_jax import replica_deletion_jax_chain
+
+    chained = replica_deletion_jax_chain(problems, backend=backend)
+    busy = problems[0].busy.copy()
+    for prob, got in zip(problems, chained):
+        seq = AssignmentProblem(busy=busy, mu=prob.mu, groups=prob.groups)
+        host = replica_deletion(seq)
+        assert got.alloc == host.alloc
+        busy = commit_busy(busy, host, seq.mu, len(busy))
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_strip_moves_fill_the_mover_rows(backend):
+    """One strip of server 0 moves 16 one-member classes, every mover row
+    the compact strip has, and leaves the running count buckets equal to
+    a rebuild from the slots."""
+    import jax.numpy as jnp
+
+    from repro.core import rd_jax
+
+    problem = _one_member_classes(32, mu=16)
+    c_cap = rd_jax.rd_slot_capacity(problem)
+    busy0 = jnp.asarray(problem.busy, jnp.int32)
+    mu = jnp.asarray(problem.mu, jnp.int32)
+    slots = [jnp.asarray(a) for a in rd_jax._dense_instance(problem, c_cap, 2)]
+    st, removed, moved = rd_jax._strip(
+        rd_jax._init_state(busy0, mu, *slots),
+        jnp.int32(0),
+        busy0,
+        mu,
+        use_pallas=backend == "pallas",
+        interpret=True,
+    )
+    assert int(moved) == int(removed) == rd_jax._MOVERS == 16
+    assert not bool(st.overflow)
+    rebuilt = rd_jax._init_state(busy0, mu, st.holders, st.size, st.cnt, st.grp)
+    np.testing.assert_array_equal(st.hist, rebuilt.hist)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("case", sorted(_COMPACT_CASES))
+def test_compact_strip_matches_reference(case, backend, monkeypatch):
+    """Single and chained, with the host re-run forbidden: a strip that
+    fills the mover rows, and recycled slots whose stale pointers must
+    be ignored."""
+    problem = _COMPACT_CASES[case]()
+
+    def _no_walk(*a, **k):
+        raise AssertionError("chained device RD fell back to host")
+
+    monkeypatch.setattr("repro.core.rd.host_commit_walk", _no_walk)
+    _assert_device_matches_reference(problem, backend, monkeypatch)
+    other = _random_instance(np.random.default_rng(5), m=problem.n_servers)
+    second = AssignmentProblem(busy=problem.busy, mu=other.mu, groups=other.groups)
+    _assert_chain_matches_sequential_host([problem, second], backend)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("chained", [False, True], ids=["single", "chained"])
+def test_quota_past_the_mover_rows_reruns_on_host(chained, backend, monkeypatch):
+    """μ = 20 (past the contract's 16) lets a strip move 20 classes: the
+    program flags overflow and the adapter re-runs on the host, with the
+    reference's assignment."""
+    from repro import obs
+    from repro.core import rd as rd_host
+    from repro.core import rd_jax
+
+    problem = _one_member_classes(40, mu=20)
+    assert bool(_device_outputs(problem, backend)[4])  # overflow flagged
+    calls = []
+    if chained:
+        walk = rd_host.host_commit_walk
+        monkeypatch.setattr(
+            rd_host, "host_commit_walk", lambda ps: calls.append(ps) or walk(ps)
+        )
+        with obs.observe(trace=False) as session:
+            _assert_chain_matches_sequential_host([problem, problem], backend)
+        assert session.metrics.counters["device.rd-chain.host_fallback"] == 1
+    else:
+        monkeypatch.setattr(
+            rd_jax, "replica_deletion", lambda p: calls.append(p) or replica_deletion(p)
+        )
+        with obs.observe(trace=False) as session:
+            dev = rd_jax.replica_deletion_jax(problem, backend=backend)
+        assert dev.alloc == replica_deletion_reference(problem).alloc
+        assert session.metrics.counters["device.rd-device.host_fallback"] == 1
+    assert len(calls) == 1
+
+
+# Loop iterations of the earlier implementation (full C × A slot-lane
+# updates) on the same instances: the compact strip does the same work.
+_PINNED_ITERS = {
+    "many_movers": (lambda: _one_member_classes(32, mu=16), 3),
+    "recycled_slot": (lambda: _RECYCLED, 44),
+    "seeded_0": (lambda: _seeded(0), 48),
+    "seeded_1": (lambda: _seeded(1), 50),
+    "seeded_2": (lambda: _seeded(2), 72),
+    "seeded_3": (lambda: _seeded(3), 70),
+    "seeded_wide": (
+        lambda: _random_instance(
+            np.random.default_rng(60), m=40, k_hi=30, size_hi=60, avail_hi=12, busy_hi=20
+        ),
+        432,
+    ),
+}
+
+
+def _seeded(seed):
+    return _random_instance(
+        np.random.default_rng(seed), m=9, k_hi=6, size_hi=20, avail_hi=5
+    )
+
+
+@pytest.mark.parametrize("case", list(_PINNED_ITERS))
+def test_loop_iterations_are_pinned(case):
+    make, iters = _PINNED_ITERS[case]
+    problem = make()
+    outs = _device_outputs(problem)
+    assert not bool(outs[4])
+    assert int(outs[5]) == iters
+    assert 0 < int(outs[6]) <= int(problem.mu.max()) * iters
+
+
+def _loop_index_ops(hlo: str) -> dict[str, list[tuple[str, int, tuple[int, ...]]]]:
+    """Per outermost ``while`` body of an HLO module: each gather and
+    scatter reached from it, as (op, elements read or written, batch shape
+    of its indices)."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        if cur is None:
+            head = re.match(r"^(?:ENTRY\s+)?([\w.\-]+)\s.*\{\s*$", line)
+            if head:
+                cur = head.group(1)
+                comps[cur] = []
+        elif line.startswith("}"):
+            cur = None
+        else:
+            comps[cur].append(line.strip())
+    shapes = {}
+    for lines in comps.values():
+        for line in lines:
+            d = re.match(r"(?:ROOT )?([\w.\-]+) = [a-z]+\d*\[([\d,]*)\]", line)
+            if d:
+                shapes[d.group(1)] = tuple(int(x) for x in d.group(2).split(",") if x)
+
+    def callees(line):
+        out = re.findall(
+            r"\b(?:to_apply|condition|body|true_computation"
+            r"|false_computation|calls)=([\w.\-]+)",
+            line,
+        )
+        for grp in re.findall(r"branch_computations=\{([^}]*)\}", line):
+            out += [c.strip() for c in grp.split(",")]
+        return out
+
+    def reach(root):
+        seen, stack = set(), [root]
+        while stack:
+            comp = stack.pop()
+            if comp not in seen:
+                seen.add(comp)
+                stack += [c for line in comps[comp] for c in callees(line)]
+        return seen
+
+    bodies = {
+        b: reach(b)
+        for ls in comps.values()
+        for ln in ls
+        for b in re.findall(r"\bbody=([\w.\-]+)", ln)
+    }
+    found = {}
+    for body, seen in bodies.items():
+        if any(body in other for b, other in bodies.items() if b != body):
+            continue  # a loop nested in another, e.g. the interpreted kernel's
+        ops = []
+        for comp in seen:
+            for line in comps[comp]:
+                op = re.match(
+                    r"(?:ROOT )?([\w.\-]+) = \S+ (gather|scatter)\(([^)]*)\)", line
+                )
+                if op is None:
+                    continue
+                args = [shapes[a.strip()] for a in op.group(3).split(",")]
+                idx = args[1]
+                ivd = int(re.search(r"index_vector_dim=(\d+)", line).group(1))
+                batch = idx[:ivd] + idx[ivd + 1 :]
+                data = shapes[op.group(1)] if op.group(2) == "gather" else args[2]
+                ops.append((op.group(2), int(np.prod(data)), batch))
+        found[body] = ops
+    return found
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_loop_bodies_index_no_slot_lanes(backend):
+    """Lowered at M 1,000, C 256, A 16, the two ``while`` bodies hold no
+    gather or scatter that reads or writes C × A (or C × A/2) slot lanes,
+    and the only ops indexed by a C-vector are the jnp sort path's own
+    two gathers (``neg_key[order]``, ``size[order]``).
+
+    The C × A implementation lowered each strip with 2 gathers of C × A
+    elements (the alt triple's busy lookup and the stale-pointer reset),
+    3 scatters of C × A or C × A/2 (``holders``, ``setkey`` and ``dest``
+    rows) and 22 ops indexed by a C-vector (the jnp path; 20 under the
+    kernel), and each deletion iteration one C × A scatter-max (the peek).
+    """
+    import jax
+
+    from repro.core.rd_jax import _rd_device
+
+    m, c, a = 1000, 256, 16
+    shapes = [(m,), (m,), (c, a), (c,), (c,), (c,)]
+    program = functools.partial(
+        _rd_device, use_pallas=backend == "pallas", interpret=True
+    )
+    hlo = (
+        jax.jit(program)
+        .lower(*(jax.ShapeDtypeStruct(s, np.int32) for s in shapes))
+        .as_text(dialect="hlo")
+    )
+    bodies = _loop_index_ops(hlo)
+    assert len(bodies) == 2  # the deletion and the dedup loop
+    for ops in bodies.values():
+        assert ops, "the strip's delta updates are inside the loop bodies"
+        assert not [o for o in ops if o[1] in (c * a, c * a // 2)]
+        by_c = [o for o in ops if o[2] == (c,)]
+        assert by_c == ([("gather", c, (c,))] * 2 if backend == "jnp" else [])
 
 
 # ---- batched burst admission ------------------------------------------------

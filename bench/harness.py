@@ -38,7 +38,16 @@ from . import check, gen, reference, spec, trace as trace_mod, warmup
 GRACE_S = 60.0
 # one event per program lowered (its backend compile may hit the cache)
 LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
-OBS_HISTS = ("tick.service.us", "sched.overhead_us")
+OBS_HISTS = (
+    "tick.service.us",
+    "sched.overhead_us",
+    "rd.prep.us",
+    "rd.wait.us",
+    "rd.readback.us",
+    "rd.decode.us",
+    "rd.iters",
+    "rd.moved",
+)
 
 
 class NoChip(RuntimeError):

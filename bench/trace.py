@@ -20,10 +20,15 @@ works on that plain data alone, so a small recorded trace checks it.
   ``XLA Modules`` lines.
 - Each idle gap is put down to the innermost host event around its
   midpoint, and gaps are summed by that name.
+- A trace in which the chip ran no op inside the window (no device
+  plane, as on the CPU, or device planes with no op event there, as when
+  the program placed every job on the host) reads busy 0: the window is
+  one idle gap, put down to host events like any other.
 """
 
 from __future__ import annotations
 
+import collections
 import glob
 import os
 import re
@@ -146,15 +151,16 @@ def reduce(planes: list[dict], top: int = 10) -> dict:
     """Busy and window seconds, launch counts and device seconds of every
     op and every program, the costliest ops and the idle gaps by host
     activity, all inside the ``bench.window`` span (times averaged over
-    the device planes that ran something)."""
+    the device planes that ran something).  ``idle_gaps`` holds the
+    ``top`` largest idle labels, ``idle_by_label`` every one, and
+    ``host_spans`` counts each host event name that starts in the window
+    on the window's host line."""
     ws, we, host = _window(planes)
     device_planes = [
         p for p in planes
         if p["name"].startswith(DEVICE_PREFIX)
         and any(line["name"] == OPS_LINE and line["events"] for line in p["lines"])
     ]
-    if not device_planes:
-        raise ValueError("no device plane with ops in the trace")
     busy_ns = 0.0
     ops: dict[str, dict] = {}
     programs: dict[str, dict] = {}
@@ -184,9 +190,9 @@ def reduce(planes: list[dict], top: int = 10) -> dict:
         merged = _merge(ivs)
         merged_all.append(merged)
         busy_ns += sum(e - s for s, e in merged)
-    n_dev = len(device_planes)
+    n_dev = max(len(device_planes), 1)
     gaps: dict[str, float] = {}
-    for merged in merged_all:
+    for merged in merged_all or [[]]:  # no device ran: the window is one gap
         edges = [ws] + [x for iv in merged for x in iv] + [we]
         idle = [(s, e) for s, e in zip(edges[0::2], edges[1::2]) if e > s]
         labels = _innermost(host, [(s + e) / 2 for s, e in idle])
@@ -202,4 +208,8 @@ def reduce(planes: list[dict], top: int = 10) -> dict:
             for n, r in sorted(ops.items(), key=lambda kv: -kv[1]["self_s"])[:top]
         ],
         "idle_gaps": sorted(([k, v] for k, v in gaps.items()), key=lambda kv: -kv[1])[:top],
+        "idle_by_label": gaps,
+        "host_spans": dict(collections.Counter(
+            name for name, start, _ in host if ws <= start < we and name != WINDOW_SPAN
+        )),
     }

@@ -56,6 +56,7 @@ OBS = {
     "rd.readback.us": (2, 400),
     "rd.decode.us": (2, 5_000),
     "rd.iters": (2, 1_000),
+    "rd.moved": (2, 2_280),
 }
 
 
@@ -78,6 +79,7 @@ def _read(name, ctx):
         ("rd_decode_ms", 2.5),
         ("rd_iters_per_job", 500.0),
         ("rd_iter_us", 450e-9 / 1_000 * 1e6),  # 450 ns of _rd_device over 1,000 iterations
+        ("rd_movers_per_iter", 2.28),  # 2,280 classes moved over 1,000 iterations
         ("idle_host_pct.backlog", 23.0),  # 30% idle less 7% waiting
     ],
 )
@@ -97,6 +99,59 @@ def test_idle_splits_into_host_and_wait():
     assert _read("device_idle_pct.backlog", ctx) == pytest.approx(
         _read("idle_host_pct.backlog", ctx) + 100 * wait / ctx.trace["window_s"]
     )
+
+
+def _gapped(spans):
+    """Host spans of 100 ns back to back, each ``(name, idle_ns)``: the
+    chip runs ops over each span but an idle gap of ``idle_ns`` in its
+    middle."""
+    host = [_ev("bench.window", 0, 100 * len(spans))]
+    ops = []
+    for i, (name, idle) in enumerate(spans):
+        s = 100 * i
+        host.append(_ev(name, s, 100))
+        ops += [(s, s + 50 - idle // 2), (s + 50 - idle // 2 + idle, s + 100)]
+    return [
+        {"name": "/host:CPU", "lines": [{"name": "python", "events": host}]},
+        {"name": "/device:TPU:0", "lines": [{"name": "XLA Ops", "events": [
+            _ev(f"%fusion.{i} = s32[8]{{0}} fusion(s32[8]{{0}} %a)", a, b - a)
+            for i, (a, b) in enumerate(ops) if b > a
+        ]}]},
+    ]
+
+
+def _backlog_ctx(spans):
+    return harness.Ctx("backlog", 64, 0.0, [], [], 0, 1.0, 0, trace=trace.reduce(_gapped(spans)))
+
+
+def test_wait_below_the_largest_ten_labels():
+    """Eleven host labels each leave the chip idle longer than ``rd.wait``
+    does, so ``rd.wait`` is not among ``idle_gaps``; the reader takes it
+    from the full table: 230 ns idle in 1,200, 10 of them waiting."""
+    ctx = _backlog_ctx([(f"host.{i}", 20) for i in range(11)] + [("rd.wait", 10)])
+    assert "rd.wait" not in dict(ctx.trace["idle_gaps"])
+    assert _read("device_idle_pct.backlog", ctx) == pytest.approx(100 * 230 / 1200)
+    assert _read("idle_host_pct.backlog", ctx) == pytest.approx(100 * 220 / 1200)
+
+
+def test_wait_with_no_idle_reads_all_idle_as_host():
+    """``rd.wait`` spans in the window but the chip busy under them: every
+    idle gap is host work."""
+    ctx = _backlog_ctx([("rd.prep", 30), ("rd.wait", 0), ("rd.decode", 40), ("rd.wait", 0)])
+    assert "rd.wait" not in ctx.trace["idle_by_label"]
+    assert ctx.trace["host_spans"]["rd.wait"] == 2
+    assert _read("idle_host_pct.backlog", ctx) == pytest.approx(
+        _read("device_idle_pct.backlog", ctx), rel=1e-12
+    )
+    assert _read("idle_host_pct.backlog", ctx) == pytest.approx(100 * 70 / 400)
+
+
+def test_no_wait_span_reads_nothing():
+    """No ``rd.wait`` span in the window (RD never dispatched to the
+    device): the share has nothing to split, though the chip idled."""
+    ctx = _backlog_ctx([("rd.host", 30), ("tick.service", 20)])
+    assert _read("device_idle_pct.backlog", ctx) == pytest.approx(25.0)
+    assert _read("idle_host_pct.backlog", ctx) is None
 
 
 def test_program_spans_reach_the_profiler_host_line(tmp_path):

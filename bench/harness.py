@@ -17,6 +17,12 @@ every device program the window will run.  The window then drives
   the tasks placed over the time from the open to the return of that
   last step.
 
+Each signature class the warm-up runs has to dispatch a device program
+(a ``device.<kind>`` span of the program's own): a class that dispatches
+none ran the cell's policy off the chip, and the run stops with
+:class:`OffChip` before the window opens, traced or not.  So does a
+traced run whose window holds no device op.
+
 The window's steps are then replayed through the plain reference
 (:mod:`reference`), and :mod:`check` compares placements and schedule.
 With ``trace`` on, an observability session and a profiler trace cover
@@ -54,6 +60,10 @@ class NoChip(RuntimeError):
     """JAX found no TPU, or fewer chips than the cell asks for."""
 
 
+class OffChip(RuntimeError):
+    """The program ran the cell's device policy off the chip."""
+
+
 def check_device(chips: int):
     import jax
 
@@ -63,6 +73,25 @@ def check_device(chips: int):
     if len(devs) < chips:
         raise NoChip(f"the cell asks for {chips} chips, JAX found {len(devs)}")
     return devs[:chips]
+
+
+def check_on_chip(cell: str, dispatched: dict[tuple, int], m: int) -> None:
+    """Every signature class warmed dispatched a device program; the
+    first that dispatched none raises :class:`OffChip`."""
+    for key, n in dispatched.items():
+        if n == 0:
+            raise OffChip(
+                f"cell {cell}: signature class {key} at M={m} dispatched {n} device"
+                " programs in the warm-up, so the program ran it off the chip"
+            )
+
+
+def _dispatches(session) -> int:
+    """The device dispatches ``session`` has counted (``device.<kind>.calls``)."""
+    return sum(
+        n for k, n in session.metrics.counters.items()
+        if k.startswith("device.") and k.endswith(".calls")
+    )
 
 
 @dataclasses.dataclass
@@ -186,12 +215,15 @@ def run(
     plane_hook=None,
     rate: float | None = None,
     sink: dict | None = None,
+    off_chip_ok: bool = False,
 ) -> dict:
     """One run; returns the result object (its ``checks`` key last).
     ``plane_hook(plane)`` may swap parts of the plane before warm-up
     (the control and the fault tests use it); ``rate`` replaces the
     mix's offered rate and ``sink`` receives the window's raw record
-    (the knee sweep uses both)."""
+    (the knee sweep uses both).  ``off_chip_ok`` leaves out the
+    warm-up's look for device programs: only the control sets it, whose
+    mirrored reference is a host program put in the program's place."""
     cell = spec.load_cell(cell_name, root)
     import jax
 
@@ -230,8 +262,18 @@ def run(
                 enqueue(job_id, assignment, gids)
 
             plane.engine.cluster.enqueue = capture
-            warmed = warmup.warm(plane.engine.policy, tr["policy"], steps, m)
-            print(f"warmed {len(warmed)} signature classes: {warmed}", file=sys.stderr, flush=True)
+            # a session of its own, so the count holds whatever this
+            # process compiled before; the window's readers never see it
+            with obs.observe(trace=False, metrics_every=1 << 30) as look:
+                warmed = warmup.warm(
+                    plane.engine.policy, tr["policy"], steps, m, lambda: _dispatches(look)
+                )
+            print(
+                f"warmed {len(warmed)} signature classes, device programs dispatched by each: {warmed}",
+                file=sys.stderr, flush=True,
+            )
+            if not off_chip_ok:
+                check_on_chip(cell_name, warmed, m)
             before = _hists(session) if trace else None
             if trace:
                 shutil.rmtree(trace_dir, ignore_errors=True)
@@ -296,8 +338,10 @@ def run(
         t_red = time.perf_counter()
         ctx.trace = trace_mod.reduce(trace_mod.load(str(trace_dir)))
         print(f"trace reduced in {time.perf_counter() - t_red:.1f} s", file=sys.stderr, flush=True)
-        ctx.peaks = spec.load_peaks(devices[0].device_kind, root)
         shutil.rmtree(trace_dir, ignore_errors=True)
+        if ctx.trace["busy_s"] == 0:
+            raise OffChip(f"cell {cell_name}: the chip ran no op in the traced window")
+        ctx.peaks = spec.load_peaks(devices[0].device_kind, root)
         breakdown = {
             "device_ops": ctx.trace["device_ops"],
             "idle_gaps": ctx.trace["idle_gaps"],
@@ -340,7 +384,7 @@ def main(argv: list[str] | None, t_start: float) -> int:
     args = ap.parse_args(argv)
     try:
         out = run(args.workload, args.seed, args.seconds, bool(args.trace), t_start)
-    except NoChip as e:
+    except (NoChip, OffChip) as e:
         print(f"bench: {e}", file=sys.stderr, flush=True)
         return 1
     for name, c in out["checks"].items():

@@ -11,6 +11,10 @@ the jit signatures they reach can be listed from the jobs:
   ``C`` its slot capacity and ``A`` its padded group width.  Placing the
   real job of a large ``C`` takes many strips, so each class is warmed
   on a stand-in job with the same ``(C, A)`` and a handful of strips.
+
+A class the program runs on the chip dispatches one or more device
+programs each time it is warmed, compiled in this process or not;
+:func:`warm` counts them per class.
 """
 
 from __future__ import annotations
@@ -86,12 +90,16 @@ def signatures(policy_name: str, steps, m: int) -> dict[tuple, object]:
     return out
 
 
-def warm(policy, policy_name: str, steps, m: int) -> list[tuple]:
-    """Run each signature class once; returns the classes warmed."""
-    sigs = signatures(policy_name, steps, m)
-    for key, what in sorted(sigs.items()):
+def warm(policy, policy_name: str, steps, m: int, dispatched) -> dict[tuple, int]:
+    """Run each signature class once; returns, for each class warmed in
+    key order, the device programs dispatched while it ran, read from the
+    running count ``dispatched()``."""
+    out = {}
+    for key, what in sorted(signatures(policy_name, steps, m).items()):
+        before = dispatched()
         if policy_name == "rd":
             policy.assign(_rd_stand_in(*what, m))
         else:
             _place_step(policy, what, m)
-    return sorted(sigs)
+        out[key] = dispatched() - before
+    return out

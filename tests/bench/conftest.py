@@ -19,8 +19,9 @@ def tiny_root(tmp_path):
 
 
 @pytest.fixture
-def cpu_harness(monkeypatch):
-    """The harness with its look for a chip skipped: the CPU stands in."""
+def chipless_harness(monkeypatch):
+    """The harness with its look for a chip skipped: the CPU stands in.
+    The warm-up's look for device programs stays on."""
     import jax
 
     from bench import harness
@@ -28,3 +29,12 @@ def cpu_harness(monkeypatch):
     monkeypatch.setattr(harness, "check_device", lambda chips: jax.devices()[:chips])
     monkeypatch.setattr(harness, "enable_cache", lambda: None)
     return harness
+
+
+@pytest.fixture
+def cpu_harness(chipless_harness, monkeypatch):
+    """The chipless harness with the warm-up's look for device programs
+    skipped too, so a policy the CPU runs on the host (RD under ``auto``)
+    runs through."""
+    monkeypatch.setattr(chipless_harness, "check_on_chip", lambda cell, dispatched, m: None)
+    return chipless_harness

@@ -1,9 +1,10 @@
 """The reduction from a profiler trace to busy time, idle gaps, kernel
 launches and program time, on a hand-built trace with known answers and
 on a small trace recorded on the chip; a window in which the chip ran
-nothing, reduced and in a whole traced run; and the byte count behind
-``wf_kernel_roofline_pct``."""
+nothing, reduced, and refused in a whole traced run; and the byte count
+behind ``wf_kernel_roofline_pct``."""
 
+import functools
 import json
 import pathlib
 
@@ -130,19 +131,20 @@ def test_chip_ran_nothing(device):
         assert read[name](ctx) is None, name
 
 
-def test_traced_run_on_a_chip_that_ran_nothing(tiny_root, cpu_harness, monkeypatch):
+def test_traced_run_on_a_chip_that_ran_nothing(tiny_root, cpu_harness, monkeypatch, capsys):
     """The whole traced run on the CPU, whose trace has no device plane
-    (as a program that places every job on the host): it completes,
-    correct, and reports the chip idle for the whole window."""
-    monkeypatch.setattr(spec, "load_peaks", lambda kind, root: {"hbm_bytes_per_s": 819e9})
-    out = cpu_harness.run("tiny-rd-backlog", 2**33 + 11, 1.0, True, 0.0, root=tiny_root)
-    assert out["correct"], out["checks"]
-    assert out["device"]["busy_s"] == 0 and out["device"]["window_s"] > 0
-    assert out["metrics"]["device_idle_pct.backlog"] == {"value": 100.0, "unit": "%"}
-    assert out["breakdown"]["device_ops"] == []
-    assert sum(v for _, v in out["breakdown"]["idle_gaps"]) > 0
-    assert not {"rd_strip_us", "rd_kernel_us", "rd_iter_us"} & set(out["metrics"])
-    assert list(out)[-1] == "checks"
+    (as a program that places every job on the host, with the warm-up's
+    look for device programs skipped): it is refused with ``OffChip``,
+    exit 1 and no result line, as a result line with busy 0 would be
+    refused as malformed."""
+    monkeypatch.setattr(cpu_harness, "run", functools.partial(cpu_harness.run, root=tiny_root))
+    argv = ["--workload", "tiny-rd-backlog", "--seed", str(2**33 + 11), "--seconds", "1.0"]
+    rc = cpu_harness.main(argv + ["--trace", "1"], 0.0)
+    out = capsys.readouterr()
+    assert rc == 1 and out.out == ""
+    assert out.err.splitlines()[-1] == (
+        "bench: cell tiny-rd-backlog: the chip ran no op in the traced window"
+    )
 
 
 def test_wf_level_bytes_count_the_real_servers():
